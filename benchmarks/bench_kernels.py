@@ -182,7 +182,7 @@ def run(quick=True):
         err = np.abs(y_k - y_ref[:, :sl]).max() / max(np.abs(y_ref).max(),
                                                       1e-9)
         flops = 2 * E * C * D * F * 3
-        resident = (bm * D * 2 + bm * D * 4 + 3 * D * bf * 2 + bm * bf * 4)
+        resident = ops.vmem_bytes(bm, bf, D)
         rows.append({
             "bench": "kernels", "label": name,
             "ref_us_per_call": us,

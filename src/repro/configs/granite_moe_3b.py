@@ -1,7 +1,7 @@
 """granite-moe-3b-a800m — fine-grained MoE, 40 experts top-8.
 
 [moe] 32L d_model=1536 24H (GQA kv=8) d_ff=512 vocab=49155, MoE 40e top-8
-[hf:ibm-granite/granite-3.0-1b-a400m-base; hf]
+[hf:ibm-granite/granite-3.0-3b-a800m; hf]
 
 Note (DESIGN.md §5): E=40 does not divide the 16-way production model axis —
 at that mesh the experts use expert-TP (d_ff sharded); at EP-divisible
@@ -27,7 +27,7 @@ CONFIG = ArchConfig(
     moe_d_ff=512,
     moe_every=1,
     mlp_gated=True,
-    source="hf:ibm-granite/granite-3.0-1b-a400m-base; hf",
+    source="hf:ibm-granite/granite-3.0-3b-a800m; hf",
 )
 
 SMOKE = dataclasses.replace(

@@ -23,6 +23,7 @@ Validated on CPU with ``interpret=True`` against ``ref.moe_ffn_ref``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -54,13 +55,17 @@ def _kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref):
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret",
+                                             "vmem_limit_bytes"))
 def fused_moe_ffn_pallas(w1, w3, w2, toks, *, bm: int = 128, bf: int = 256,
-                         interpret: bool = False):
+                         interpret: bool = False,
+                         vmem_limit_bytes: Optional[int] = None):
     """toks (E, C, D), w1/w3 (E, D, F), w2 (E, F, D) → (E, C, D).
 
     C is padded to a multiple of ``bm`` and F to a multiple of ``bf``
     (zero padding is exact for SwiGLU — see module docstring).
+    ``vmem_limit_bytes`` raises the compiler's scoped-VMEM limit for large
+    blocks (``ops.pick_blocks`` sizes them).
     """
     E, C, D = toks.shape
     F = w1.shape[-1]
@@ -89,6 +94,8 @@ def fused_moe_ffn_pallas(w1, w3, w2, toks, *, bm: int = 128, bf: int = 256,
         out_specs=pl.BlockSpec((1, bm, D), lambda e, i, f: (e, i, 0)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, D), toks.dtype),
         scratch_shapes=[pltpu.VMEM((bm, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(toks, w1, w3, w2)
     return out[:, :C] if pc else out

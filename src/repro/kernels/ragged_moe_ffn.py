@@ -29,6 +29,7 @@ Validated on CPU with ``interpret=True`` against ``ref.ragged_moe_ffn_ref``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -98,9 +99,11 @@ def _kernel(g_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref, *,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bf", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bf", "interpret",
+                                             "vmem_limit_bytes"))
 def ragged_moe_ffn_pallas(w1, w3, w2, toks, tile_group, *, bf: int = 256,
-                          interpret: bool = False):
+                          interpret: bool = False,
+                          vmem_limit_bytes: Optional[int] = None):
     """toks (T, D) group-sorted flat buffer, tile_group (T // bm,) int32,
     w1/w3 (E, D, F), w2 (E, F, D) → (T, D).
 
@@ -108,7 +111,8 @@ def ragged_moe_ffn_pallas(w1, w3, w2, toks, tile_group, *, bf: int = 256,
     to a multiple of ``bf`` (zero padding is exact for SwiGLU). Tiles whose
     ``tile_group`` is the sentinel ``E`` are skipped (zeros out); occupied
     tiles fetch their expert's weight blocks through the scalar-prefetch
-    index maps.
+    index maps. ``vmem_limit_bytes`` raises the compiler's scoped-VMEM
+    limit for large blocks (``ops.pick_blocks`` sizes them).
     """
     T, D = toks.shape
     n_tiles = tile_group.shape[0]
@@ -140,5 +144,7 @@ def ragged_moe_ffn_pallas(w1, w3, w2, toks, tile_group, *, bf: int = 256,
         functools.partial(_kernel, n_groups=E),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, D), toks.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(tile_group, toks, w1, w3, w2)

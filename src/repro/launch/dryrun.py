@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import ALL_ARCHS, EXTRA_ARCHS, SHAPES, get, shape_applicable
 from repro.models import (decode_fn, init_params, loss_fn,
                           make_moe_tables, prefill_fn)
@@ -149,7 +148,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
         spec = input_specs(arch, shape_name, mesh)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = _build_lowered(spec, mesh)
             t1 = time.time()
             compiled = lowered.compile()
@@ -173,7 +172,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         except Exception as e:                      # pragma: no cover
             rec["memory_error"] = str(e)
         try:
-            ca = compat.cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis()
             rec["xla_cost"] = {k: float(ca[k]) for k in
                                ("flops", "bytes accessed") if k in ca}
         except Exception as e:                      # pragma: no cover

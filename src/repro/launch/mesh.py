@@ -3,16 +3,14 @@
 Functions, not module-level constants — importing this module never touches
 jax device state (smoke tests must keep seeing 1 device).
 
-Mesh construction goes through :mod:`repro.compat` so the same call works
-on JAX versions with and without ``jax.sharding.AxisType`` (0.4.x meshes
-are implicitly Auto-typed).
+Meshes are Auto-typed: ``jax.make_mesh`` defaults to Explicit axes, and the
+model's shard_map / sharding-constraint code is written for Auto ones.
 """
 
 from __future__ import annotations
 
 import jax
-
-from repro import compat
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh"]
 
@@ -34,9 +32,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}; have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax (launch/dryrun.py does this)")
-    return compat.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
-def make_mesh(shape, axes):
-    """Arbitrary test mesh with Auto axis types (shard_map-compatible)."""
-    return compat.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, devices=None):
+    """Mesh with Auto axis types (shard_map-compatible) over ``devices``
+    (default: all devices)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

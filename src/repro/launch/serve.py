@@ -1,9 +1,10 @@
 """Serving driver: the JAX engine with ViBE end-to-end on real routing.
 
-Brings up a smoke-scale model in the continuous-batching engine, profiles
-the cluster (Alg 1 Phase 1), computes the initial placement (Phase 2),
-serves with drift-aware recalibration (Phase 3) and reports SLO metrics
-against the virtual clock (DESIGN.md §4).
+Brings up a smoke-scale model (``--full``: the published config) in the
+continuous-batching engine, profiles the cluster (Alg 1 Phase 1),
+computes the initial placement (Phase 2), serves with drift-aware
+recalibration (Phase 3) and reports SLO metrics against the virtual clock
+(DESIGN.md §4).
 
 The engine side is configured through :class:`EngineConfig`: pick a
 scheduler from the registry (``--scheduler slo_edf``), enable chunked
@@ -13,6 +14,12 @@ or a multi-tenant arrival trace (``--workload bursty``).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-moe-235b-a22b \
         --requests 12 --policy vibe --scheduler slo_edf --workload bursty
+
+At published width on one TPU v5e (granite fits the chip whole):
+
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-moe-3b-a800m \
+        --full --policy vibe_r --max-batch 8 --max-seq 2048 \
+        --prefill-chunk 256 --requests 8
 """
 
 from __future__ import annotations
@@ -21,14 +28,16 @@ import argparse
 import dataclasses
 from typing import Optional, Union
 
+import jax
 import numpy as np
 
-from repro.configs import get_smoke
+from repro.configs import get, get_smoke
 from repro.core import (DriftConfig, PerfDriftConfig, SCENARIOS, StealConfig,
                         ViBEConfig, ViBEController, default_slots_per_rank,
                         get_policy, make_cluster, make_scenario, parse_topology,
                         registered_policies)
-from repro.models import moe_perm_shape
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import init_cache, init_params, moe_perm_shape
 from repro.serving import (ChaosReport, Engine, EngineConfig, FaultSchedule,
                            KVCacheConfig, SchedulerConfig, TRACES, WORKLOADS,
                            registered_schedulers, run_chaos,
@@ -38,23 +47,31 @@ from repro.serving import (ChaosReport, Engine, EngineConfig, FaultSchedule,
 __all__ = ["serve", "derive_slot_budget", "main"]
 
 
-def derive_slot_budget(n_ranks: int, n_experts: int, expert_bytes: int,
+def derive_slot_budget(n_ranks: int, n_experts: int, slot_bytes: int,
+                       reserved_bytes: int = 0,
                        spec: Union[str, int, None] = "auto"):
     """Per-rank physical slot budget from device memory telemetry.
 
+    ``slot_bytes`` is what one physical expert slot costs on the device:
+    its w1/w3/w2 over *every* MoE layer. ``reserved_bytes`` is what the
+    rest of the engine will hold next to the experts (non-expert
+    parameters, the KV cache and the step's fresh output cache).
+
     ``spec``:
 
-    * ``"auto"``  — query the local accelerator's allocator
-      (``jax.Device.memory_stats``) for free HBM, emulate ``n_ranks``
-      devices sharing it, and size each rank's replica budget by how many
-      expert tensors fit in its share after a safety margin. Hosts
-      without memory telemetry (the CPU CI runner) fall back
-      deterministically to the policy-default budget, so smoke runs are
-      identical across hosts.
+    * ``"auto"``  — read the local device's allocator
+      (``jax.Device.memory_stats``). Of the free bytes left after
+      ``reserved_bytes``, 80% may hold expert slots plus the transient of
+      a migration, which regathers one of the three expert matrices at a
+      time (a third of the slots' bytes on top). ``n_ranks`` emulated
+      ranks share that room. Devices without memory telemetry (the CPU)
+      fall back deterministically to the policy-default budget, so CPU
+      runs are identical across hosts.
     * ``"default"`` / ``None`` — policy-default budget (returns None).
     * an integer — uniform per-rank budget, passed through.
 
     Returns a ``(n_ranks,)`` int array or None (= let the policy choose).
+    Raises when even the policy default does not fit the device.
     """
     if spec in (None, "default", ""):
         return None
@@ -64,26 +81,37 @@ def derive_slot_budget(n_ranks: int, n_experts: int, expert_bytes: int,
         raise ValueError("slots_per_rank must be 'auto', 'default' or an "
                          f"integer, got {spec!r}")
     base = default_slots_per_rank(n_experts, n_ranks)
-    stats = None
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        stats = None
+    stats = jax.local_devices()[0].memory_stats()
     if not stats:
         # deterministic CPU fallback: exactly the policy-default budget
         return np.full(n_ranks, base, dtype=np.int64)
-    free = int(stats.get("bytes_limit", 0)) - int(stats.get("bytes_in_use", 0))
-    if free <= 0:
-        return np.full(n_ranks, base, dtype=np.int64)
-    # 80% of this emulated rank's share of free memory holds its experts;
+    free = int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
+    room = 0.8 * (free - reserved_bytes)
+    fit = int(room / (n_ranks * slot_bytes * 4 / 3))
+    if fit < base:
+        raise ValueError(
+            f"{n_ranks} ranks x {base} expert slots of {slot_bytes} B do not "
+            f"fit: {free} B free, {reserved_bytes} B reserved")
     # clamp to [policy default, E) so the budget always solves
-    fit = int(0.8 * free / n_ranks / max(expert_bytes, 1))
-    per_rank = int(np.clip(fit, base, max(n_experts - 1, base)))
+    per_rank = min(fit, max(n_experts - 1, base))
     return np.full(n_ranks, per_rank, dtype=np.int64)
 
 
-def serve(arch: str, *, policy: str = "vibe", n_requests: int = 12,
+def _resident_bytes(cfg, max_batch: int, max_seq: int) -> int:
+    """Device bytes the engine holds besides its expert slots: the
+    non-expert parameters, and the KV cache twice (a step returns a fresh
+    cache while its input is still alive)."""
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    total = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
+    experts = 3 * cfg.d_model * cfg.moe_d_ff * cfg.n_experts * 2
+    n_moe, _ = moe_perm_shape(cfg, None, "train")
+    cache = jax.eval_shape(lambda: init_cache(cfg, max_batch, max_seq))
+    kv = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    return int(total - experts * n_moe + 2 * kv)
+
+
+def serve(arch: str, *, smoke: bool = True, policy: str = "vibe",
+          n_requests: int = 12,
           qps: float = 50.0, workload: str = "sharegpt",
           regime: str = "mi325x", max_batch: int = 4, max_seq: int = 96,
           adaptive: bool = True, weighted_routing: bool = True,
@@ -103,7 +131,7 @@ def serve(arch: str, *, policy: str = "vibe", n_requests: int = 12,
     if chaos and fail_rank >= 0:
         raise SystemExit("--chaos and --fail-rank are mutually exclusive "
                          "(a chaos schedule already includes rank faults)")
-    cfg = get_smoke(arch)
+    cfg = get_smoke(arch) if smoke else get(arch)
     if not cfg.is_moe:
         raise SystemExit(f"{arch} has no MoE layers — ViBE serving n/a")
     n_moe, n_slots = moe_perm_shape(cfg, None, "train")
@@ -134,8 +162,9 @@ def serve(arch: str, *, policy: str = "vibe", n_requests: int = 12,
     # allocation); other policies keep their fixed footprint.
     budget = None
     if get_policy(policy).capabilities.accepts_slot_budget:
-        budget = derive_slot_budget(ranks, cfg.n_experts, expert_bytes,
-                                    slots_per_rank)
+        budget = derive_slot_budget(
+            ranks, cfg.n_experts, expert_bytes * n_moe,
+            _resident_bytes(cfg, max_batch, max_seq), slots_per_rank)
     controller = ViBEController(
         n_moe, n_slots, ranks, perf,
         ViBEConfig(policy=policy, adaptive=adaptive,
@@ -191,6 +220,9 @@ def serve(arch: str, *, policy: str = "vibe", n_requests: int = 12,
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-moe-235b-a22b")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="serve the published config instead of its smoke "
+                         "reduction")
     ap.add_argument("--policy", default="vibe",
                     choices=list(registered_policies()))
     ap.add_argument("--requests", type=int, default=12)
@@ -290,7 +322,9 @@ def main() -> int:
                          "(0 = routing-only recalibration, the default)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
-    engine, records, report = serve(args.arch, policy=args.policy,
+    enable_compile_cache()
+    engine, records, report = serve(args.arch, smoke=args.smoke,
+                            policy=args.policy,
                             n_requests=args.requests, qps=args.qps,
                             workload=args.workload, regime=args.regime,
                             max_batch=args.max_batch, max_seq=args.max_seq,

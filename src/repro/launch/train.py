@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, loss_fn, make_moe_tables
 from repro.training import (AdamWConfig, Checkpointer, DataConfig,
                             adamw_init, adamw_update, cosine_lr,
@@ -97,6 +98,7 @@ def main() -> int:
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     _, _, losses, tallies = train(
         args.arch, smoke=args.smoke, steps=args.steps, seq_len=args.seq_len,
         batch=args.batch, ckpt_dir=args.ckpt_dir, seed=args.seed)

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from .attention import attn_init
 from .common import apply_rope, dense_init, mlp, mlp_init, rms_norm, \
@@ -312,11 +311,11 @@ def _run_attention(p, x, cfg, rules, window, positions, cache=None,
                                        window=win, q_positions=qpos,
                                        kv_positions=kpos)
 
-            out = compat.shard_map(
+            out = jax.shard_map(
                 body, mesh=rules.mesh,
                 in_specs=(qspec, kvspec, kvspec, rules.spec(rules.tp),
                           P(), P()),
-                out_specs=qspec,
+                out_specs=qspec, check_vma=False,
             )(q, k, v, positions, positions, win)
         else:
             if rules is not None:
@@ -373,11 +372,11 @@ def _run_attention(p, x, cfg, rules, window, positions, cache=None,
             out = (num / jnp.maximum(den, 1e-30)[..., None]).astype(q.dtype)
             return out, kc, vc
 
-        out, k_cache, v_cache = compat.shard_map(
+        out, k_cache, v_cache = jax.shard_map(
             body, mesh=rules.mesh,
             in_specs=(qspec, qspec, qspec, cspec, cspec,
                       rules.spec(b_ax)),
-            out_specs=(qspec, cspec, cspec),
+            out_specs=(qspec, cspec, cspec), check_vma=False,
         )(q, k, v, k_cache, v_cache, pos)
     else:
         k_cache = k_cache.at[jnp.arange(B), pos].set(k[:, 0])

@@ -59,8 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from .common import dense_init
 from .sharding import ShardingRules
 
@@ -336,30 +334,41 @@ def _aux_loss(tally, mean_prob, n_experts):
 # ragged (dropless) dispatch
 # ---------------------------------------------------------------------------
 
-def _ragged_local_ffn(xf, tok_flat, wgt_flat, slot_flat, active, n_groups,
-                      bm, ffn, w1, w3, w2):
+def _combine_top_k(y_rows, weights):
+    """Gate-weighted sum of each token's top-k results: ``y_rows`` (t·K, D)
+    in assignment order (token-major), ``weights`` (t, K) → (t, D) f32.
+
+    A reshape-and-sum, not a scatter-add at slot-sorted token ids: on a TPU
+    that scatter-add, fed by the slot sort, lost most contributions (XLA
+    TPU backend, JAX 0.9.0), while this form matched the oracle."""
+    t, K = weights.shape
+    y = y_rows.astype(jnp.float32).reshape(t, K, -1)
+    return (y * weights.astype(jnp.float32)[:, :, None]).sum(1)
+
+
+def _ragged_local_ffn(xf, weights, slot_flat, active, n_groups, bm, ffn,
+                      w1, w3, w2):
     """Sorted-buffer grouped FFN + weighted combine for local assignments.
 
-    Builds the ragged plan over ``slot_flat``, scatters each (active)
-    assignment's token row into the flat expert-sorted buffer, runs the
-    grouped FFN over occupied tiles, and scatter-adds the gate-weighted
-    results back per token. Inactive assignments land out of bounds (their
+    ``weights`` (t, K) are the gate weights, ``slot_flat`` (t·K,) the
+    assignments' slots in token-major order. Builds the ragged plan,
+    scatters each (active) assignment's token row into the flat
+    expert-sorted buffer, runs the grouped FFN over occupied tiles, and
+    gathers each assignment's result back in assignment order for the
+    weighted combine. Inactive assignments land out of bounds (their
     scatters drop, their gathers clamp and are zero-weighted). Returns the
     (t, D) f32 partial output — dropless by construction.
     """
-    t, D = xf.shape
+    t, K = weights.shape
     order, rows, tile_group, n_rows = _ragged_plan(slot_flat, n_groups, bm,
                                                    active)
-    tok_s = tok_flat[order]
-    buf = jnp.zeros((n_rows, D), xf.dtype).at[rows].set(
-        xf[tok_s], mode="drop")
+    buf = jnp.zeros((n_rows, xf.shape[1]), xf.dtype).at[rows].set(
+        xf[order // K], mode="drop")
     y_buf = ffn(w1, w3, w2, buf, tile_group)
-    wgt_s = wgt_flat[order]
+    row_of = jnp.zeros_like(rows).at[order].set(rows)   # assignment order
     if active is not None:
-        wgt_s = wgt_s * active[order].astype(wgt_s.dtype)
-    contrib = (y_buf[jnp.minimum(rows, n_rows - 1)].astype(jnp.float32)
-               * wgt_s[:, None])
-    return jnp.zeros((t, D), jnp.float32).at[tok_s].add(contrib)
+        weights = weights * active.reshape(t, K).astype(weights.dtype)
+    return _combine_top_k(y_buf[jnp.minimum(row_of, n_rows - 1)], weights)
 
 
 def _dense_dispatch_ragged(p, xf, route_seed, *, top_k, n_experts, slots_of,
@@ -372,11 +381,8 @@ def _dense_dispatch_ragged(p, xf, route_seed, *, top_k, n_experts, slots_of,
         weights = weights * row_valid[:, None].astype(weights.dtype)
     slots = _select_slots(idx, slots_of, n_copies, copy_cdf, route_seed)
     n_slots = p["w1"].shape[0]
-    t = xf.shape[0]
-    tok_flat = jnp.repeat(jnp.arange(t, dtype=jnp.int32), top_k)
-    out = _ragged_local_ffn(xf, tok_flat, weights.reshape(-1),
-                            slots.reshape(-1), None, n_slots, bm, ffn,
-                            p["w1"], p["w3"], p["w2"])
+    out = _ragged_local_ffn(xf, weights, slots.reshape(-1), None, n_slots,
+                            bm, ffn, p["w1"], p["w3"], p["w2"])
     tally = _masked_tally(idx, n_experts, row_valid)
     aux = _aux_loss(tally, mean_prob, n_experts)
     tally = jnp.concatenate([tally, jnp.zeros((1,), jnp.float32)])
@@ -414,8 +420,6 @@ def _a2a_body_ragged(xb, router_w, w1, w3, w2, slots_of, n_copies, copy_cdf,
     weights, idx, mean_prob = route(router_w, xf, top_k)
     slots = _select_slots(idx, slots_of, n_copies, copy_cdf, route_seed)
     slot_flat = slots.reshape(-1)
-    wgt_flat = weights.reshape(-1)
-    tok_flat = jnp.repeat(jnp.arange(t, dtype=jnp.int32), top_k)
     A = t * top_k
 
     # sorted send: slot-major order == (dest rank, local slot) order, so
@@ -427,7 +431,7 @@ def _a2a_body_ragged(xb, router_w, w1, w3, w2, slots_of, n_copies, copy_cdf,
     pos_in_rank = jnp.arange(A, dtype=jnp.int32) - rank_starts[rank_sorted]
     send_row = rank_sorted * A + pos_in_rank
     send = jnp.zeros((ep * A, D), xf.dtype).at[send_row].set(
-        xf[tok_flat[order]])
+        xf[order // top_k])
     # local-slot ids per frame row; e_loc = padding sentinel
     loc_ids = jnp.full((ep * A,), e_loc, jnp.int32).at[send_row].set(
         ss % e_loc)
@@ -452,9 +456,8 @@ def _a2a_body_ragged(xb, router_w, w1, w3, w2, slots_of, n_copies, copy_cdf,
     back = jax.lax.all_to_all(y_recv.reshape(ep, A, D), a2a_axes,
                               split_axis=0, concat_axis=0).reshape(R, D)
 
-    contrib = (back[send_row].astype(jnp.float32)
-               * wgt_flat[order][:, None])
-    out = jnp.zeros((t, D), jnp.float32).at[tok_flat[order]].add(contrib)
+    send_row_of = jnp.zeros_like(send_row).at[order].set(send_row)
+    out = _combine_top_k(back[send_row_of], weights)
 
     tally = jax.nn.one_hot(idx, n_experts, dtype=jnp.float32).sum((0, 1))
     tally = jnp.concatenate([tally, jnp.zeros((1,))])   # dropless: tally[E]=0
@@ -483,16 +486,13 @@ def _replicated_body_ragged(xb, router_w, w1, w3, w2, slots_of, n_copies,
         my_rank = my_rank * sz + jax.lax.axis_index(a)
 
     xf = xb.reshape(B * S, D)
-    t = xf.shape[0]
     weights, idx, mean_prob = route(router_w, xf, top_k)
     slots = _select_slots(idx, slots_of, n_copies, copy_cdf, route_seed)
     slot_flat = slots.reshape(-1)
-    tok_flat = jnp.repeat(jnp.arange(t, dtype=jnp.int32), top_k)
 
-    mine = (slot_flat // e_loc) == my_rank
-    out = _ragged_local_ffn(xf, tok_flat, weights.reshape(-1),
-                            slot_flat % e_loc, mine, e_loc, bm, ffn,
-                            w1, w3, w2)
+    mine =(slot_flat // e_loc) == my_rank
+    out = _ragged_local_ffn(xf, weights, slot_flat % e_loc, mine, e_loc, bm,
+                            ffn, w1, w3, w2)
     out = jax.lax.psum(out, psum_axes)
 
     tally = jax.nn.one_hot(idx, n_experts, dtype=jnp.float32).sum((0, 1))
@@ -513,7 +513,7 @@ def _a2a_body(xb, router_w, w1, w3, w2, slots_of, n_copies, copy_cdf,
     xb: (B_loc, S_loc, D). Expert weights arrive sharded (E_loc, D/f, F)
     with axis 1 FSDP-sharded; gathered here (ZeRO-3, transposes to
     reduce-scatter in the backward). ``ep`` is the static EP group size
-    (mesh shape is known at trace time; old JAX has no lax.axis_size).
+    (the mesh shape is known at trace time).
     """
     Bl, Sl, D = xb.shape
     e_loc = n_slots // ep
@@ -729,7 +729,7 @@ def moe_layer(
                 fsdp_axes=fsdp_axes, ffn=ffn)
         ep_spec = ep_axes[0] if len(ep_axes) == 1 else ep_axes
         w_spec = P(ep_spec, fsdp_axes if fsdp_axes else None, None)
-        out, tally, aux = compat.shard_map(
+        out, tally, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(dp_axes if dp_axes else None, ep_spec, None),
                       P(None, None), w_spec, w_spec,
@@ -737,6 +737,7 @@ def moe_layer(
                       P(None, None), P(None), P(None, None), P()),
             out_specs=(P(dp_axes if dp_axes else None, ep_spec, None),
                        P(None), P()),
+            check_vma=False,
         )(x, p["router"], p["w1"], p["w3"], p["w2"], slots_of, n_copies,
           copy_cdf, route_seed)
         return out, tally, aux
@@ -768,13 +769,14 @@ def moe_layer(
             n_slots=n_slots, capacity=capacity, ep_axes=ep_axes,
             ep_sizes=tuple(rules.axis_size(a) for a in ep_axes), ffn=ffn,
             psum_axes=ep_axes + ftp_axes)
-    out, tally, aux = compat.shard_map(
+    out, tally, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, None), P(None, None),
                   P(ep_spec, None, ftp_spec), P(ep_spec, None, ftp_spec),
                   P(ep_spec, ftp_spec, None), P(None, None), P(None),
                   P(None, None), P()),
         out_specs=(P(None, None, None), P(None), P()),
+        check_vma=False,
     )(x, p["router"], p["w1"], p["w3"], p["w2"], slots_of, n_copies,
       copy_cdf, route_seed)
     return out, tally, aux

@@ -238,10 +238,12 @@ class Engine:
         for i, spec in enumerate(specs):
             if spec.ffn != "moe":
                 continue
-            leaf = self.params["blocks"][i]["ffn"]
-            grown = {k: jnp.take(leaf[k], gi, axis=1)
-                     for k in ("w1", "w2", "w3") if k in leaf}
-            self.params["blocks"][i]["ffn"] = {**leaf, **grown}
+            # one matrix at a time, so only one grown copy is transient
+            for k in ("w1", "w2", "w3"):
+                leaf = self.params["blocks"][i]["ffn"]
+                if k in leaf:
+                    self.params["blocks"][i]["ffn"] = {
+                        **leaf, k: jnp.take(leaf[k], gi, axis=1)}
         self._perm = np.tile(src, (self.n_moe, 1))
         self.n_slots = n_slots
 
@@ -290,9 +292,13 @@ class Engine:
         for jj, i in enumerate(moe_positions):
             old_j = self._perm[jj::m] if m else self._perm
             new_j = new_perm[jj::m]
-            leaf = self.params["blocks"][i]["ffn"]
-            migrated, moved = apply_placement(leaf, old_j, new_j)
-            self.params["blocks"][i]["ffn"] = {**leaf, **migrated}
+            # one matrix at a time, so only one migrated copy is transient
+            for k in ("w1", "w2", "w3"):
+                leaf = self.params["blocks"][i]["ffn"]
+                if k in leaf:
+                    migrated, moved = apply_placement({k: leaf[k]}, old_j,
+                                                      new_j)
+                    self.params["blocks"][i]["ffn"] = {**leaf, **migrated}
             moved_total += moved
         self._perm = new_perm.copy()
         self._share = None if share is None else np.array(share)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.models import moe as MOE
 from repro.models.sharding import ShardingRules
 
@@ -68,7 +68,7 @@ def setup():
     p = MOE.moe_init(jax.random.PRNGKey(0), d=D, f=F, n_experts=E, n_slots=E)
     x = jax.random.normal(jax.random.PRNGKey(1), (B, S, D)) \
         .astype(jnp.bfloat16)
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     return p, x, mesh
 
 
@@ -76,7 +76,7 @@ def _run_a2a(p, x, mesh, cf):
     rules = ShardingRules(mesh=mesh, dp=(), ep=("model",), fsdp=None,
                           moe_dispatch="a2a", capacity_factor=cf,
                           moe_impl="capacity")
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, tally, _ = jax.jit(lambda p, x: MOE.moe_layer(
             p, x, top_k=K, n_experts=E, rules=rules, phase="train"))(p, x)
     return np.asarray(y, np.float32), np.asarray(tally)
@@ -132,7 +132,7 @@ def test_replicated_path_surfaces_drops(setup):
                           ep_all=("model",), fsdp=None,
                           moe_dispatch="replicated", capacity_factor=2.0,
                           moe_impl="capacity")
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, tally, _ = jax.jit(lambda p, x: MOE.moe_layer(
             p, x, top_k=1, n_experts=E, rules=rules, phase="decode"))(
             p_hot, x_pos)

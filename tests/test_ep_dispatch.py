@@ -19,13 +19,13 @@ import sys
 sys.path.insert(0, os.environ['REPRO_SRC'])
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.models.sharding import ShardingRules, build_copy_cdf, \
     build_slots_of
 from repro.models import moe as MOE
 
-set_mesh = compat.use_mesh
-mesh = compat.make_mesh((2, 4), ('data', 'model'))
+set_mesh = jax.set_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 E, D, F, K = 16, 64, 128, 4
 p = MOE.moe_init(jax.random.PRNGKey(0), d=D, f=F, n_experts=E, n_slots=E)
 B, S = 4, 8

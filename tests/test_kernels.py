@@ -97,9 +97,17 @@ def test_property_moe_ffn_matches_oracle(seed):
 
 def test_ops_wrapper_picks_valid_blocks():
     bm, bf = ops.pick_blocks(8192, 24576)
-    resident = bm * 8192 * 2 + bm * 8192 * 4 + 3 * 8192 * bf * 2 + bm * bf * 4
-    assert resident <= 14 * 1024 * 1024
+    # double-buffered x / output / weight blocks + f32 scratch
+    resident = (2 * 2 * bm * 8192 * 2 + 2 * 3 * 8192 * bf * 2
+                + bm * 8192 * 4 + 3 * bm * bf * 4)
+    assert ops.vmem_bytes(bm, bf, 8192) == resident
+    assert resident <= ops._TILE_BUDGET < ops._VMEM_LIMIT
     assert bm % 128 == 0 and bf % 128 == 0
+    # a pinned row tile (the ragged kernel's) is kept; one that cannot fit
+    # raises instead of falling back to an unchecked tile
+    assert ops.pick_blocks(8192, 24576, bm=128)[0] == 128
+    with pytest.raises(ValueError, match="no grouped-FFN block fits"):
+        ops.pick_blocks(8192, 24576, bm=512)
 
 
 def test_kernel_is_dispatch_compatible():

@@ -27,7 +27,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.kernels.ragged_moe_ffn import (ragged_moe_ffn_pallas,
                                           ragged_n_tiles,
                                           ragged_tile_metadata)
@@ -181,7 +181,7 @@ def setup():
     p = MOE.moe_init(jax.random.PRNGKey(0), d=D, f=F, n_experts=E, n_slots=E)
     x = jax.random.normal(jax.random.PRNGKey(1), (B, S, D)) \
         .astype(jnp.bfloat16)
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     return p, x, mesh
 
 
@@ -189,7 +189,7 @@ def _run(p, x, mesh, *, dispatch, impl, cf, phase, top_k=K, bm=8):
     rules = ShardingRules(mesh=mesh, dp=(), ep=("model",), ep_all=("model",),
                           fsdp=None, moe_dispatch=dispatch,
                           capacity_factor=cf, moe_impl=impl, moe_block_m=bm)
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, tally, _ = jax.jit(lambda p, x: MOE.moe_layer(
             p, x, top_k=top_k, n_experts=E, rules=rules, phase=phase))(p, x)
     return np.asarray(y, np.float32), np.asarray(tally)
@@ -268,7 +268,7 @@ def test_ragged_gradients_flow(setup):
                                 phase="train")
         return (y.astype(jnp.float32) ** 2).mean() + 0.01 * a
 
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(loss))(p, x)
     for k, v in g.items():
         assert float(jnp.linalg.norm(v.astype(jnp.float32))) > 0, k
@@ -291,7 +291,7 @@ def test_ragged_weighted_replica_routing(setup):
     rules = ShardingRules(mesh=mesh, dp=(), ep=("model",), fsdp=None,
                           moe_dispatch="a2a", moe_impl="ragged",
                           moe_block_m=8)
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, tally, _ = jax.jit(lambda pp, xx: MOE.moe_layer(
             pp, xx, top_k=K, n_experts=E, rules=rules,
             slots_of=jnp.asarray(so[0]), n_copies=jnp.asarray(nc[0]),

@@ -92,8 +92,7 @@ def test_hlo_parser_trip_count_exact():
         costs = parse_hlo(c.as_text())
         expect = 2 * 32 * 128 * 128 * L
         assert costs.flops == pytest.approx(expect, rel=1e-6)
-        from repro.compat import cost_analysis_dict
-        ca = cost_analysis_dict(c)   # list-of-dicts on 0.4.x, dict on newer
+        ca = c.cost_analysis()
         # rel=0.05 absorbs elementwise-op flops; a trip-count-multiplying
         # XLA would be off by ~L×, far outside this tolerance
         assert ca["flops"] == pytest.approx(2 * 32 * 128 * 128, rel=0.05), \
@@ -108,6 +107,61 @@ def test_serve_driver_end_to_end():
     done = [r for r in records if np.isfinite(r.finished_at)]
     assert len(done) == 3
     assert engine.stats.virtual_time > 0
+
+
+class _Device:
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def _granite_slot_sizes():
+    from repro.launch.serve import _resident_bytes
+    from repro.models import moe_perm_shape
+    cfg = get("granite-moe-3b-a800m")
+    n_moe, _ = moe_perm_shape(cfg, None, "train")
+    slot_bytes = 3 * cfg.d_model * cfg.moe_d_ff * 2 * n_moe
+    return cfg, slot_bytes, _resident_bytes(cfg, 8, 2048)
+
+
+def test_slot_budget_fits_a_16gb_device(monkeypatch):
+    """granite at published width, 8 lanes x 2048 positions, on a device
+    with 16 GB free: the expanded experts (with one matrix regathered by a
+    migration) plus the non-expert parameters and the KV cache fit."""
+    from repro.core import default_slots_per_rank
+    from repro.launch import serve as serve_mod
+    cfg, slot_bytes, reserved = _granite_slot_sizes()
+    limit = 16 * 10**9
+    monkeypatch.setattr(serve_mod.jax, "local_devices", lambda: [
+        _Device({"bytes_limit": limit, "bytes_in_use": 0})])
+    budget = serve_mod.derive_slot_budget(8, cfg.n_experts, slot_bytes,
+                                          reserved)
+    assert budget.shape == (8,)
+    assert budget.min() >= default_slots_per_rank(cfg.n_experts, 8)
+    experts = int(budget.sum()) * slot_bytes
+    assert experts * 4 / 3 + reserved <= limit
+    # the budget the old one-layer sizing gave would not have fit
+    assert 8 * int(0.8 * limit / 8 / (slot_bytes // 32)) * slot_bytes > limit
+
+
+def test_slot_budget_refuses_a_device_too_small(monkeypatch):
+    from repro.launch import serve as serve_mod
+    cfg, slot_bytes, reserved = _granite_slot_sizes()
+    monkeypatch.setattr(serve_mod.jax, "local_devices", lambda: [
+        _Device({"bytes_limit": 8 * 10**9, "bytes_in_use": 0})])
+    with pytest.raises(ValueError, match="do not fit"):
+        serve_mod.derive_slot_budget(8, cfg.n_experts, slot_bytes, reserved)
+
+
+def test_slot_budget_cpu_falls_back_to_policy_default():
+    from repro.core import default_slots_per_rank
+    from repro.launch.serve import derive_slot_budget
+    cfg, slot_bytes, reserved = _granite_slot_sizes()
+    budget = derive_slot_budget(8, cfg.n_experts, slot_bytes, reserved)
+    np.testing.assert_array_equal(
+        budget, np.full(8, default_slots_per_rank(cfg.n_experts, 8)))
 
 
 def test_vibe_beats_eplb_on_skewed_system_e2e():

@@ -116,9 +116,9 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import sys; sys.path.insert(0, {repr(os.path.join(os.path.dirname(__file__), '..', 'src'))})
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.training import load_checkpoint
-mesh = compat.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 like = {{'w': jnp.zeros((8, 8), jnp.float32)}}
 sh = {{'w': NamedSharding(mesh, P('data', 'model'))}}
 tree, _ = load_checkpoint({repr(d)}, 1, like, shardings=sh)
