@@ -458,14 +458,16 @@ def _block_body(cfg, rules, specs, bp, x, *, windows_blk, moe_tables_blk,
             if windows_blk is not None:
                 window = windows_blk[i]
             cache = None if cache_blk is None else cache_blk[i]
-            if phase == "chunk":
-                lane, offset, n_valid, row_valid = chunk_ctx
-                h, st = _run_attention_chunk(
-                    sub["mixer"], h, cfg, window, cache, positions,
-                    lane, offset, n_valid, row_valid)
-            else:
-                h, st = _run_attention(sub["mixer"], h, cfg, rules, window,
-                                       positions, cache=cache, pos=pos)
+            with jax.named_scope("attention"):
+                if phase == "chunk":
+                    lane, offset, n_valid, row_valid = chunk_ctx
+                    h, st = _run_attention_chunk(
+                        sub["mixer"], h, cfg, window, cache, positions,
+                        lane, offset, n_valid, row_valid)
+                else:
+                    h, st = _run_attention(sub["mixer"], h, cfg, rules,
+                                           window, positions, cache=cache,
+                                           pos=pos)
             new_cache.append(st)
         else:
             st_in = None if cache_blk is None else cache_blk[i]
@@ -497,14 +499,17 @@ def _block_body(cfg, rules, specs, bp, x, *, windows_blk, moe_tables_blk,
                 if chunk_ctx is not None:
                     rv = jnp.broadcast_to(chunk_ctx[3][None, :],
                                           h2.shape[:2]).reshape(-1)
-                y, tally, aux = moe_layer(
-                    sub["ffn"], h2, top_k=cfg.top_k,
-                    n_experts=cfg.n_experts, rules=rules,
-                    slots_of=so, n_copies=nc, copy_cdf=cdf,
-                    route_seed=seed, phase=phase, row_valid=rv)
-                if cfg.n_shared_experts:
-                    tp = None if rules is None else P(rules.dp, None, rules.tp)
-                    y = y + mlp(sub["shared"], h2, cfg.mlp_gated, tp_spec=tp)
+                with jax.named_scope("moe"):
+                    y, tally, aux = moe_layer(
+                        sub["ffn"], h2, top_k=cfg.top_k,
+                        n_experts=cfg.n_experts, rules=rules,
+                        slots_of=so, n_copies=nc, copy_cdf=cdf,
+                        route_seed=seed, phase=phase, row_valid=rv)
+                    if cfg.n_shared_experts:
+                        tp = None if rules is None \
+                            else P(rules.dp, None, rules.tp)
+                        y = y + mlp(sub["shared"], h2, cfg.mlp_gated,
+                                    tp_spec=tp)
                 tallies.append(tally)
                 aux_total = aux_total + aux
                 moe_i += 1
@@ -522,6 +527,11 @@ def _block_body(cfg, rules, specs, bp, x, *, windows_blk, moe_tables_blk,
 
 def _embed(cfg, params, batch, rules):
     """Token/feature embedding → (x (B,S,D), labels_offset)."""
+    with jax.named_scope("embed"):
+        return _embed_inputs(cfg, params, batch, rules)
+
+
+def _embed_inputs(cfg, params, batch, rules):
     if cfg.frontend == "audio":
         x = jnp.einsum("bsf,fd->bsd", batch["feats"],
                        params["frontend"])
@@ -542,6 +552,13 @@ def _embed(cfg, params, batch, rules):
 
 def _unembed_w(cfg, params):
     return params["embed"].T if cfg.tie_embeddings else params["head"]
+
+
+def _unembed_f32(cfg, params, x, spec: str):
+    """Logits of ``x`` against the head, both in float32."""
+    with jax.named_scope("unembed"):
+        return jnp.einsum(spec, x.astype(jnp.float32),
+                          _unembed_w(cfg, params).astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -616,7 +633,7 @@ def loss_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None,
 def prefill_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
     """(params, batch) → (last-position logits, cache, tallies)."""
 
-    def fn(params, batch, moe_tables=None):
+    def prefill(params, batch, moe_tables=None):
         x, off = _embed(cfg, params, batch, rules)
         S = x.shape[1]
         positions = jnp.arange(S)
@@ -624,11 +641,10 @@ def prefill_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
             cfg, rules, params, x, phase="prefill", moe_tables=moe_tables,
             positions=positions)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bd,dv->bv", x[:, -1].astype(jnp.float32),
-                            _unembed_w(cfg, params).astype(jnp.float32))
+        logits = _unembed_f32(cfg, params, x[:, -1], "bd,dv->bv")
         return logits, cache, tallies
 
-    return fn
+    return prefill
 
 
 def prefill_chunk_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
@@ -657,7 +673,8 @@ def prefill_chunk_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
             "chunked prefill is single-device (the serving engine's "
             "configuration); mesh sharding is not supported")
 
-    def fn(params, tokens, cache, lane, offset, n_valid, moe_tables=None):
+    def prefill_chunk(params, tokens, cache, lane, offset, n_valid,
+                      moe_tables=None):
         x, _ = _embed(cfg, params, {"tokens": tokens}, rules)
         C = x.shape[1]
         positions = offset + jnp.arange(C)
@@ -668,17 +685,16 @@ def prefill_chunk_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
             chunk_ctx=(lane, offset, n_valid, row_valid))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = jnp.take(x[0], jnp.maximum(n_valid - 1, 0), axis=0)
-        logits = jnp.einsum("d,dv->v", last.astype(jnp.float32),
-                            _unembed_w(cfg, params).astype(jnp.float32))
+        logits = _unembed_f32(cfg, params, last, "d,dv->v")
         return logits[None], new_cache, tallies
 
-    return fn
+    return prefill_chunk
 
 
 def decode_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
     """(params, token (B,1), cache, pos) → (logits, new cache, tallies)."""
 
-    def fn(params, token, cache, pos, moe_tables=None):
+    def decode_step(params, token, cache, pos, moe_tables=None):
         """``pos``: (B,) per-sequence positions (continuous batching)."""
         x, _ = _embed(cfg, params, {"tokens": token}, rules)
         pos = jnp.broadcast_to(jnp.asarray(pos), (token.shape[0],))
@@ -686,11 +702,10 @@ def decode_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
             cfg, rules, params, x, phase="decode", moe_tables=moe_tables,
             positions=pos, cache=cache, pos=pos)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bd,dv->bv", x[:, -1].astype(jnp.float32),
-                            _unembed_w(cfg, params).astype(jnp.float32))
+        logits = _unembed_f32(cfg, params, x[:, -1], "bd,dv->bv")
         return logits, new_cache, tallies
 
-    return fn
+    return decode_step
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int,

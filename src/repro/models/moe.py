@@ -118,11 +118,12 @@ def route(router_w: jnp.ndarray, xf: jnp.ndarray, top_k: int):
     indices (t, K) i32 (logical), and mean full-softmax probs (E,) f32 for
     the load-balance aux loss.
     """
-    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), router_w)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = jax.lax.top_k(probs, top_k)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
-    return weights, idx.astype(jnp.int32), probs.mean(axis=0)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), router_w)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = jax.lax.top_k(probs, top_k)
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+        return weights, idx.astype(jnp.int32), probs.mean(axis=0)
 
 
 def expert_ffn_ref(w1, w3, w2, toks):
@@ -298,19 +299,23 @@ def _select_slots(idx: jnp.ndarray, slots_of: jnp.ndarray,
 def _dense_dispatch(p, xf, route_seed, *, top_k, n_experts, slots_of,
                     n_copies, copy_cdf, row_valid=None):
     weights, idx, mean_prob = route(p["router"], xf, top_k)
-    if row_valid is not None:
-        # padded rows (chunked prefill): no gate weight, no tally — they
-        # must be invisible to both the output and the routing telemetry
-        weights = weights * row_valid[:, None].astype(weights.dtype)
-    slots = _select_slots(idx, slots_of, n_copies, copy_cdf,
-                          route_seed)                   # (t, K) physical
     n_slots = p["w1"].shape[0]
-    # scatter gate weights into a (t, n_slots) combine matrix
-    comb = jnp.zeros((xf.shape[0], n_slots), jnp.float32).at[
-        jnp.arange(xf.shape[0])[:, None], slots].add(weights)
-    y = expert_ffn_ref(p["w1"], p["w3"], p["w2"],
-                       jnp.broadcast_to(xf, (n_slots,) + xf.shape))
-    out = jnp.einsum("te,etd->td", comb, y.astype(jnp.float32))
+    with jax.named_scope("dispatch"):
+        if row_valid is not None:
+            # padded rows (chunked prefill): no gate weight, no tally —
+            # they must be invisible to both the output and the routing
+            # telemetry
+            weights = weights * row_valid[:, None].astype(weights.dtype)
+        slots = _select_slots(idx, slots_of, n_copies, copy_cdf,
+                              route_seed)               # (t, K) physical
+        # scatter gate weights into a (t, n_slots) combine matrix
+        comb = jnp.zeros((xf.shape[0], n_slots), jnp.float32).at[
+            jnp.arange(xf.shape[0])[:, None], slots].add(weights)
+    with jax.named_scope("ffn"):
+        y = expert_ffn_ref(p["w1"], p["w3"], p["w2"],
+                           jnp.broadcast_to(xf, (n_slots,) + xf.shape))
+    with jax.named_scope("combine"):
+        out = jnp.einsum("te,etd->td", comb, y.astype(jnp.float32))
     tally = _masked_tally(idx, n_experts, row_valid)
     aux = _aux_loss(tally, mean_prob, n_experts)
     # dense computes every expert on every token: nothing can be dropped
@@ -360,15 +365,19 @@ def _ragged_local_ffn(xf, weights, slot_flat, active, n_groups, bm, ffn,
     (t, D) f32 partial output — dropless by construction.
     """
     t, K = weights.shape
-    order, rows, tile_group, n_rows = _ragged_plan(slot_flat, n_groups, bm,
-                                                   active)
-    buf = jnp.zeros((n_rows, xf.shape[1]), xf.dtype).at[rows].set(
-        xf[order // K], mode="drop")
-    y_buf = ffn(w1, w3, w2, buf, tile_group)
-    row_of = jnp.zeros_like(rows).at[order].set(rows)   # assignment order
-    if active is not None:
-        weights = weights * active.reshape(t, K).astype(weights.dtype)
-    return _combine_top_k(y_buf[jnp.minimum(row_of, n_rows - 1)], weights)
+    with jax.named_scope("dispatch"):
+        order, rows, tile_group, n_rows = _ragged_plan(slot_flat, n_groups,
+                                                       bm, active)
+        buf = jnp.zeros((n_rows, xf.shape[1]), xf.dtype).at[rows].set(
+            xf[order // K], mode="drop")
+    with jax.named_scope("ffn"):
+        y_buf = ffn(w1, w3, w2, buf, tile_group)
+    with jax.named_scope("combine"):
+        row_of = jnp.zeros_like(rows).at[order].set(rows)  # assignment order
+        if active is not None:
+            weights = weights * active.reshape(t, K).astype(weights.dtype)
+        return _combine_top_k(y_buf[jnp.minimum(row_of, n_rows - 1)],
+                              weights)
 
 
 def _dense_dispatch_ragged(p, xf, route_seed, *, top_k, n_experts, slots_of,
@@ -377,9 +386,10 @@ def _dense_dispatch_ragged(p, xf, route_seed, *, top_k, n_experts, slots_of,
     (A = t·top_k rows) instead of the dense oracle's every-expert-on-every-
     token broadcast. Same return contract as ``_dense_dispatch``."""
     weights, idx, mean_prob = route(p["router"], xf, top_k)
-    if row_valid is not None:
-        weights = weights * row_valid[:, None].astype(weights.dtype)
-    slots = _select_slots(idx, slots_of, n_copies, copy_cdf, route_seed)
+    with jax.named_scope("dispatch"):
+        if row_valid is not None:
+            weights = weights * row_valid[:, None].astype(weights.dtype)
+        slots = _select_slots(idx, slots_of, n_copies, copy_cdf, route_seed)
     n_slots = p["w1"].shape[0]
     out = _ragged_local_ffn(xf, weights, slots.reshape(-1), None, n_slots,
                             bm, ffn, p["w1"], p["w3"], p["w2"])

@@ -21,6 +21,11 @@ Configuration is one frozen :class:`EngineConfig` (serving/config.py):
   interleaved with decode steps, and each chunk is priced on the virtual
   clock individually, so long-context requests stop head-of-line-blocking
   TTFT.
+* **Host spans** — each phase of :meth:`Engine.step` (schedule, admit,
+  launch, sync, observe, migrate, finish) is a ``step.*`` span of
+  :mod:`repro.serving.tracing`: profiler annotations while a trace is
+  taken, one shared no-op otherwise. ``RequestRecord.admitted_step`` is
+  the step count at admission, so a client can time queue wait.
 
 Because this host has one CPU device, wall-clock here is meaningless for
 multi-rank behaviour; the engine keeps a *virtual clock* driven by the same
@@ -55,9 +60,17 @@ from .scheduler import (RequestView, SchedulerContext, get_scheduler,
                         shed_victims)
 from .simulator import (capacity_bucket_rows, rank_latency_matrix,
                         realized_rank_loads)
+from .tracing import span
 from .workload import Request
 
 __all__ = ["Engine", "EngineStats", "EngineConfig"]
+
+
+@jax.jit
+def sample(logits):
+    """Greedy next token of each row of ``logits``: its argmax."""
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, -1).astype(jnp.int32)
 
 
 @dataclasses.dataclass
@@ -109,6 +122,9 @@ class Engine:
     # class-level fallback: skeleton engines built without __init__
     # (pricing-path tests use Engine.__new__) read default knobs here
     config = EngineConfig()
+    # the last step's kind ("chunk" | "prefill" | "decode" | "idle"), an
+    # arg of every host span; "" before the first step
+    _kind = ""
 
     def __init__(self, cfg: ArchConfig,
                  config: Optional[EngineConfig] = None, *,
@@ -285,32 +301,19 @@ class Engine:
         """
         if share is Engine._AUTO_SHARE:
             share = self._controller_share()
-        nb, specs = block_layout(self.cfg)
-        m = self.n_moe // nb
-        moved_total = 0
-        moe_positions = [i for i, s in enumerate(specs) if s.ffn == "moe"]
-        for jj, i in enumerate(moe_positions):
-            old_j = self._perm[jj::m] if m else self._perm
-            new_j = new_perm[jj::m]
-            # one matrix at a time, so only one migrated copy is transient
-            for k in ("w1", "w2", "w3"):
-                leaf = self.params["blocks"][i]["ffn"]
-                if k in leaf:
-                    migrated, moved = apply_placement({k: leaf[k]}, old_j,
-                                                      new_j)
-                    self.params["blocks"][i]["ffn"] = {**leaf, **migrated}
-            moved_total += moved
-        self._perm = new_perm.copy()
-        self._share = None if share is None else np.array(share)
-        self.moe_tables = make_moe_tables(self.cfg, self.rules,
-                                          perm=self._perm,
-                                          n_slots=self.n_slots,
-                                          share=self._share,
-                                          r_max=self._r_max)
+        with span("step.migrate", kind=self._kind) as sp:
+            moved_total = self._migrate_experts(new_perm)
+            self._share = None if share is None else np.array(share)
+            self.moe_tables = make_moe_tables(self.cfg, self.rules,
+                                              perm=self._perm,
+                                              n_slots=self.n_slots,
+                                              share=self._share,
+                                              r_max=self._r_max)
+            moved_bytes = moved_total * 3 * self.cfg.d_model \
+                * self.cfg.moe_d_ff * 2
+            sp.set_metadata(slots=moved_total, bytes=moved_bytes)
         self._sync_steal_version()
         if charge:
-            per_slot = 3 * self.cfg.d_model * self.cfg.moe_d_ff * 2
-            moved_bytes = moved_total * per_slot
             self.stats.migrations += 1
             self.stats.migrated_slots += moved_total
             self.stats.migration_bytes += moved_bytes
@@ -327,6 +330,27 @@ class Engine:
                 else:
                     self.stats.virtual_time += \
                         moved_bytes / self.cluster.ici_bw
+        return moved_total
+
+    def _migrate_experts(self, new_perm: np.ndarray) -> int:
+        """Move the stacked expert weights to ``new_perm``; the number of
+        (layer, slot) tensors that moved."""
+        nb, specs = block_layout(self.cfg)
+        m = self.n_moe // nb
+        moved_total = 0
+        moe_positions = [i for i, s in enumerate(specs) if s.ffn == "moe"]
+        for jj, i in enumerate(moe_positions):
+            old_j = self._perm[jj::m] if m else self._perm
+            new_j = new_perm[jj::m]
+            # one matrix at a time, so only one migrated copy is transient
+            for k in ("w1", "w2", "w3"):
+                leaf = self.params["blocks"][i]["ffn"]
+                if k in leaf:
+                    migrated, moved = apply_placement({k: leaf[k]}, old_j,
+                                                      new_j)
+                    self.params["blocks"][i]["ffn"] = {**leaf, **migrated}
+            moved_total += moved
+        self._perm = new_perm.copy()
         return moved_total
 
     def _observe(self, tallies: np.ndarray, tokens: float) -> None:
@@ -357,9 +381,11 @@ class Engine:
         the clock charges only the small share-table broadcast.
         """
         rs = self.controller.rescheduler
-        self._share = np.array(rs.placement.share)
-        self.moe_tables = refresh_moe_share_tables(
-            self.cfg, self.moe_tables, self._perm, self._share)
+        with span("step.migrate", kind=self._kind, slots=0,
+                  bytes=rs.share_table_bytes):
+            self._share = np.array(rs.placement.share)
+            self.moe_tables = refresh_moe_share_tables(
+                self.cfg, self.moe_tables, self._perm, self._share)
         self._sync_steal_version()
         self.stats.steal_updates += 1
         if self.cluster is not None:
@@ -658,10 +684,18 @@ class Engine:
         starvation blocks every waiting request.
 
         Returns False when idle (no waiting or running requests).
+
+        Each host phase is a :func:`~repro.serving.tracing.span` (no-op
+        unless tracing is enabled) with the step's kind as an arg.
         """
-        self._shed_overload()
-        self._maybe_preempt()
-        action = self.scheduler.schedule(self._build_context())
+        with span("step.schedule") as sp:
+            self._shed_overload()
+            self._maybe_preempt()
+            action = self.scheduler.schedule(self._build_context())
+            self._kind = action.kind
+            if action.kind == "prefill" and self._chunk > 0:
+                self._kind = "chunk"
+            sp.set_metadata(kind=self._kind)
         if action.kind == "prefill":
             # the engine runs one chunk per step so the virtual clock
             # prices every chunk individually (the simulator's scheduled
@@ -680,42 +714,60 @@ class Engine:
     def _exec_prefill(self, req_id: int) -> None:
         st = self._prefilling.get(req_id)
         if st is None:
-            # admission: reserve a lane + the full worst-case KV block
-            # count (so decode extension can never fail mid-request)
-            r = next(x for x in self.waiting if x.req_id == req_id)
-            self.waiting = collections.deque(
-                x for x in self.waiting if x.req_id != req_id)
-            lane = self._free_slot()
-            self.kv.allocate(r.req_id,
-                             min(r.prompt_len + r.output_len, self.max_seq))
-            # the engine can't start before the request arrives
-            self.stats.virtual_time = max(self.stats.virtual_time, r.arrival)
-            prompt = np.random.default_rng(r.req_id).integers(
-                0, self.cfg.vocab, size=(1, r.prompt_len))
-            st = _Prefilling(r, lane, prompt)
-            self._prefilling[req_id] = st
+            with span("step.admit", kind=self._kind, req_id=req_id):
+                st = self._admit(req_id)
         if self._chunk > 0:
             self._prefill_one_chunk(st)
         else:
             self._prefill_whole(st)
 
+    def _admit(self, req_id: int) -> _Prefilling:
+        """Reserve a lane and the full worst-case KV block count (so decode
+        extension can never fail mid-request) for a waiting request."""
+        r = next(x for x in self.waiting if x.req_id == req_id)
+        self.waiting = collections.deque(
+            x for x in self.waiting if x.req_id != req_id)
+        lane = self._free_slot()
+        self.kv.allocate(r.req_id,
+                         min(r.prompt_len + r.output_len, self.max_seq))
+        rec = self.records[r.req_id]
+        if rec.admitted_step is None:
+            # a re-admitted request (preempted, or drained by a rank
+            # failure) keeps its first admission, as it keeps its TTFT
+            rec.admitted_step = self.stats.steps
+        # the engine can't start before the request arrives
+        self.stats.virtual_time = max(self.stats.virtual_time, r.arrival)
+        prompt = np.random.default_rng(r.req_id).integers(
+            0, self.cfg.vocab, size=(1, r.prompt_len))
+        st = _Prefilling(r, lane, prompt)
+        self._prefilling[req_id] = st
+        return st
+
+    def _sync_observe(self, tallies, tokens: float) -> None:
+        """Wait for the step's routing tallies on the host, then price the
+        step and feed the controller (:meth:`observe_step`)."""
+        with span("step.sync", kind=self._kind):
+            tall = np.asarray(tallies)
+        if self.cfg.is_moe and tall.size:
+            self.stats.dropped_assignments += float(tall[:, -1].sum())
+        with span("step.observe", kind=self._kind):
+            self.observe_step(tall, tokens)
+
     def _prefill_whole(self, st: _Prefilling) -> None:
         """Legacy whole-prompt prefill (``prefill_chunk = 0``)."""
         r = st.req
-        batch = {"tokens": jnp.asarray(st.prompt, jnp.int32)}
-        logits, pre_cache, tallies = self._prefill(
-            self.params, batch, self.moe_tables)
-        self._insert_cache(st.lane, pre_cache)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        self.tokens = self.tokens.at[st.lane, 0].set(nxt[0])
-        st.prefilled = r.prompt_len
-        self.kv.advance(r.req_id, min(r.prompt_len, self.max_seq))
-        self.stats.prefill_tokens += r.prompt_len
-        tall = np.asarray(tallies)
-        if self.cfg.is_moe and tall.size:
-            self.stats.dropped_assignments += float(tall[:, -1].sum())
-        self.observe_step(tall, float(r.prompt_len))
-        self._finish_prefill(st)
+        with span("step.launch", kind=self._kind):
+            batch = {"tokens": jnp.asarray(st.prompt, jnp.int32)}
+            logits, pre_cache, tallies = self._prefill(
+                self.params, batch, self.moe_tables)
+            self._insert_cache(st.lane, pre_cache)
+            self.tokens = self.tokens.at[st.lane, 0].set(sample(logits)[0])
+            st.prefilled = r.prompt_len
+            self.kv.advance(r.req_id, min(r.prompt_len, self.max_seq))
+            self.stats.prefill_tokens += r.prompt_len
+        self._sync_observe(tallies, float(r.prompt_len))
+        with span("step.finish", kind=self._kind):
+            self._finish_prefill(st)
         self.stats.prefill_steps += 1
 
     def _prefill_one_chunk(self, st: _Prefilling) -> None:
@@ -724,27 +776,26 @@ class Engine:
         C = self._chunk
         off = st.prefilled
         n_valid = min(C, r.prompt_len - off)
-        buf = np.zeros((1, C), np.int64)
-        buf[0, :n_valid] = st.prompt[0, off:off + n_valid]
-        logits, self.cache, tallies = self._prefill_chunk(
-            self.params, jnp.asarray(buf, jnp.int32), self.cache,
-            st.lane, off, n_valid, self.moe_tables)
-        st.prefilled += n_valid
-        self.kv.advance(r.req_id, n_valid)
-        self.stats.prefill_tokens += n_valid
-        # interleaved decode steps write a garbage row at pos[lane] for
-        # reserved lanes; parking pos at the next chunk offset makes the
-        # next chunk's first (always-valid) row overwrite it
-        self.pos[st.lane] = st.prefilled
-        tall = np.asarray(tallies)
-        if self.cfg.is_moe and tall.size:
-            self.stats.dropped_assignments += float(tall[:, -1].sum())
-        self.observe_step(tall, float(n_valid))
+        with span("step.launch", kind=self._kind):
+            buf = np.zeros((1, C), np.int64)
+            buf[0, :n_valid] = st.prompt[0, off:off + n_valid]
+            logits, self.cache, tallies = self._prefill_chunk(
+                self.params, jnp.asarray(buf, jnp.int32), self.cache,
+                st.lane, off, n_valid, self.moe_tables)
+            st.prefilled += n_valid
+            self.kv.advance(r.req_id, n_valid)
+            self.stats.prefill_tokens += n_valid
+            # interleaved decode steps write a garbage row at pos[lane] for
+            # reserved lanes; parking pos at the next chunk offset makes
+            # the next chunk's first (always-valid) row overwrite it
+            self.pos[st.lane] = st.prefilled
+        self._sync_observe(tallies, float(n_valid))
         self.stats.chunk_steps += 1
         if st.prefilled >= r.prompt_len:
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            self.tokens = self.tokens.at[st.lane, 0].set(nxt[0])
-            self._finish_prefill(st)
+            with span("step.finish", kind=self._kind):
+                self.tokens = self.tokens.at[st.lane, 0].set(
+                    sample(logits)[0])
+                self._finish_prefill(st)
             self.stats.prefill_steps += 1
 
     def _finish_prefill(self, st: _Prefilling) -> None:
@@ -765,34 +816,35 @@ class Engine:
             self._release(st.lane)
 
     def _exec_decode(self) -> None:
-        active = [b for b in range(self.max_batch)
-                  if self.slot_req[b] is not None]
-        pos = jnp.asarray(np.minimum(self.pos, self.max_seq - 1), jnp.int32)
-        logits, self.cache, tallies = self._decode(
-            self.params, self.tokens, self.cache, pos, self.moe_tables)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        self.tokens = nxt[:, None]
-        tall = np.asarray(tallies)
-        if self.cfg.is_moe and tall.size:
-            self.stats.dropped_assignments += float(tall[:, -1].sum())
-        self.observe_step(tall, float(len(active)))
-        self.stats.decode_tokens += len(active)
-        for b in active:
-            if self.pos[b] < self.max_seq:
-                # the new token occupied a fresh cache row (beyond
-                # max_seq the write is clamped onto the last row)
-                self.kv.extend(self.slot_req[b].req_id)
-            self.pos[b] += 1
-            self.slot_left[b] -= 1
-            if self.slot_left[b] <= 0 or self.pos[b] >= self.max_seq - 1:
-                r = self.slot_req[b]
-                rec = self.records[r.req_id]
-                rec.finished_at = self.stats.virtual_time
-                # decode participations so far = (output_len-1) - slot_left
-                # (exact even for the early max_seq-clamp finish)
-                self.stats.useful_tokens += r.prompt_len + max(
-                    int(r.output_len - 1 - self.slot_left[b]), 0)
-                self._release(b)
+        with span("step.launch", kind=self._kind):
+            active = [b for b in range(self.max_batch)
+                      if self.slot_req[b] is not None]
+            pos = jnp.asarray(np.minimum(self.pos, self.max_seq - 1),
+                              jnp.int32)
+            logits, self.cache, tallies = self._decode(
+                self.params, self.tokens, self.cache, pos, self.moe_tables)
+            self.tokens = sample(logits)[:, None]
+        self._sync_observe(tallies, float(len(active)))
+        with span("step.finish", kind=self._kind):
+            self.stats.decode_tokens += len(active)
+            for b in active:
+                if self.pos[b] < self.max_seq:
+                    # the new token occupied a fresh cache row (beyond
+                    # max_seq the write is clamped onto the last row)
+                    self.kv.extend(self.slot_req[b].req_id)
+                self.pos[b] += 1
+                self.slot_left[b] -= 1
+                if self.slot_left[b] <= 0 \
+                        or self.pos[b] >= self.max_seq - 1:
+                    r = self.slot_req[b]
+                    rec = self.records[r.req_id]
+                    rec.finished_at = self.stats.virtual_time
+                    # decode participations so far = (output_len-1) -
+                    # slot_left (exact even for the early max_seq-clamp
+                    # finish)
+                    self.stats.useful_tokens += r.prompt_len + max(
+                        int(r.output_len - 1 - self.slot_left[b]), 0)
+                    self._release(b)
         self.stats.decode_steps += 1
 
     def run(self, max_steps: int = 10_000) -> List[RequestRecord]:

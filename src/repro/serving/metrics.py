@@ -61,6 +61,9 @@ class RequestRecord:
     requeues: int = 0              # total trips back to the waiting queue
     #                                (rank-failure drains + preemptions) —
     #                                the bounded-retry/backoff ledger
+    admitted_step: Optional[int] = None   # Engine.stats.steps at the step
+    #                                that first admitted it (lane + KV);
+    #                                a step count, not a time
 
     @property
     def rejected(self) -> bool:
