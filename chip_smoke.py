@@ -235,8 +235,9 @@ def phase_serve() -> None:
     # logits and tallies of the compiled steps on the engine's live state
     warm = _cache_sizes(engine)
     pos = jnp.asarray(np.minimum(engine.pos, S - 1), jnp.int32)
-    logits, _, tall = engine._decode(engine.params, engine.tokens,
-                                     engine.cache, pos, engine.moe_tables)
+    # the step programs donate the cache: the engine keeps their output
+    logits, engine.cache, tall = engine._decode(
+        engine.params, engine.tokens, engine.cache, pos, engine.moe_tables)
     logits, tall = np.asarray(logits), np.asarray(tall)
     _check(logits.shape == (B, cfg.vocab) and np.isfinite(logits).all(),
            f"decode logits {logits.shape} not finite")
@@ -246,8 +247,8 @@ def phase_serve() -> None:
     n_valid = 100
     buf = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab, (1, C)), jnp.int32)
-    logits, _, tall = engine._prefill_chunk(engine.params, buf, engine.cache,
-                                            0, 0, n_valid, engine.moe_tables)
+    logits, engine.cache, tall = engine._prefill_chunk(
+        engine.params, buf, engine.cache, 0, 0, n_valid, engine.moe_tables)
     logits, tall = np.asarray(logits), np.asarray(tall)
     _check(np.isfinite(logits).all(), "chunk logits not finite")
     per_layer = tall[:, :cfg.n_experts].sum(1)
@@ -281,7 +282,7 @@ def _prefill_then_decode(pre, dec, params, tokens, mt, max_seq):
     S = tokens.shape[1]
     logits, cache, _ = pre(params, {"tokens": tokens}, mt)
     cache = jax.tree.map(
-        lambda c: jnp.pad(c, [(0, 0), (0, 0), (0, max_seq - S), (0, 0),
+        lambda c: jnp.pad(c, [(0, 0), (0, 0), (0, 0), (0, max_seq - S),
                               (0, 0)]), cache)
     nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
     pos = jnp.full((tokens.shape[0],), S, jnp.int32)
@@ -408,8 +409,8 @@ def phase_sharded_step() -> None:
             logits, cache, tall = jax.jit(prefill_fn(cfg, r_pre))(
                 params, {"tokens": tokens}, mt_pre)
             cache = jax.tree.map(
-                lambda c: jnp.pad(c, [(0, 0), (0, 0), (0, max_seq - S),
-                                      (0, 0), (0, 0)]), cache)
+                lambda c: jnp.pad(c, [(0, 0), (0, 0), (0, 0),
+                                      (0, max_seq - S), (0, 0)]), cache)
             _, cspec = cache_specs(cfg, r_dec, B, max_seq)
             cache = jax.device_put(cache, tree_shardings(mesh, cspec))
             p_dec = jax.device_put(params, tree_shardings(

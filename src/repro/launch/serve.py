@@ -99,8 +99,9 @@ def derive_slot_budget(n_ranks: int, n_experts: int, slot_bytes: int,
 
 def _resident_bytes(cfg, max_batch: int, max_seq: int) -> int:
     """Device bytes the engine holds besides its expert slots: the
-    non-expert parameters, and the KV cache twice (a step returns a fresh
-    cache while its input is still alive)."""
+    non-expert parameters, and the KV cache twice. The step programs now
+    update the cache in place, so one copy is live; the second stays
+    reserved until the slot budget is re-derived (it changes placement)."""
     shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     total = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
     experts = 3 * cfg.d_model * cfg.moe_d_ff * cfg.n_experts * 2

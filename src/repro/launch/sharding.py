@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.models import ShardingRules, init_cache, init_params
+from repro.models import ShardingRules, block_layout, init_cache, init_params
 
 __all__ = ["make_rules", "param_specs", "batch_specs", "cache_specs",
            "tree_shardings", "FSDP_THRESHOLD"]
@@ -204,16 +204,17 @@ def cache_specs(cfg: ArchConfig, rules: ShardingRules, batch: int,
     heads = rules.attn_mode == "heads"
     tp_size = rules.axis_size(tp)
 
-    def spec(leaf):
-        if leaf.ndim == 5 and leaf.shape[2] == max_seq:
-            # attention KV cache (nb, B, S, KV, hd)
-            if heads and cfg.n_kv_heads % max(tp_size, 1) == 0:
-                return rules.spec(None, b_ax, None if dp_ok else tp,
-                                  tp if dp_ok else None, None)
-            # context mode: shard the sequence (flash-decode psums)
-            seq_ax = tp if dp_ok else (dp + (tp,) if isinstance(dp, tuple)
-                                       else (dp, tp))
-            return rules.spec(None, b_ax, seq_ax, None, None)
+    def kv_spec(leaf):
+        # attention K or V (nb, B, KV, S, hd)
+        if heads and cfg.n_kv_heads % max(tp_size, 1) == 0:
+            return rules.spec(None, b_ax, tp if dp_ok else None,
+                              None if dp_ok else tp, None)
+        # context mode: shard the sequence (flash-decode psums)
+        seq_ax = tp if dp_ok else (dp + (tp,) if isinstance(dp, tuple)
+                                   else (dp, tp))
+        return rules.spec(None, b_ax, None, seq_ax, None)
+
+    def state_spec(leaf):
         if leaf.ndim == 5:
             # mlstm C (nb, B, H, hd, hd)
             h_ok = leaf.shape[2] % max(tp_size, 1) == 0
@@ -230,7 +231,10 @@ def cache_specs(cfg: ArchConfig, rules: ShardingRules, batch: int,
             return rules.spec(None, b_ax, None)
         return rules.spec(*([None] * leaf.ndim))
 
-    return shapes, jax.tree.map(spec, shapes)
+    _, layout = block_layout(cfg)
+    return shapes, [jax.tree.map(kv_spec if s.mixer == "attn" else state_spec,
+                                 entry)
+                    for s, entry in zip(layout, shapes)]
 
 
 def tree_shardings(mesh: Mesh, spec_tree: Any) -> Any:

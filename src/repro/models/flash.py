@@ -6,7 +6,8 @@ module implements the online-softmax algorithm with both query and key/value
 chunking via ``lax.scan`` so peak memory is O(Cq · Ckv) per (batch, head)
 instead of O(S²), while producing bit-comparable results (fp32 accumulation).
 
-GQA layout: q (B, Sq, KV, G, hd), k/v (B, Skv, KV, hd) where G = H / KV.
+GQA layout: q (B, Sq, KV, G, hd), k/v (B, Skv, KV, hd) where G = H / KV;
+the decode cache is (B, KV, S_max, hd), the layout its row writes use.
 
 Sliding-window and causal masking are data (position arrays + scalar window),
 not structure, so local/global gemma3 layers share one compiled body.
@@ -132,7 +133,7 @@ def flash_attention(
 
 def flash_decode(
     q: jnp.ndarray,                   # (B, KV, G, hd) — one new token
-    k_cache: jnp.ndarray,             # (B, S_max, KV, hd)
+    k_cache: jnp.ndarray,             # (B, KV, S_max, hd)
     v_cache: jnp.ndarray,
     pos: jnp.ndarray,                 # (B,) per-sequence positions
     *,
@@ -147,7 +148,7 @@ def flash_decode(
     and keeps the (B, S_max) score row in chunks. ``pos`` is per-sequence —
     continuous batching serves sequences at different positions in one step.
     """
-    B, S_max, KV, hd = k_cache.shape
+    B, KV, S_max, hd = k_cache.shape
     G = q.shape[2]
     pos = jnp.broadcast_to(jnp.asarray(pos), (B,))
     kv_chunk = min(kv_chunk, S_max)
@@ -162,10 +163,10 @@ def flash_decode(
     def one_chunk(carry, j):
         m, l, acc = carry
         start = j * kv_chunk
-        kj = jax.lax.dynamic_slice_in_dim(k_cache, start, kv_chunk, axis=1)
-        vj = jax.lax.dynamic_slice_in_dim(v_cache, start, kv_chunk, axis=1)
+        kj = jax.lax.dynamic_slice_in_dim(k_cache, start, kv_chunk, axis=2)
+        vj = jax.lax.dynamic_slice_in_dim(v_cache, start, kv_chunk, axis=2)
         kp = kpos_offset + start + jnp.arange(kv_chunk)
-        s = jnp.einsum("bkgh,bskh->bkgs", q, kj,
+        s = jnp.einsum("bkgh,bksh->bkgs", q, kj,
                        preferred_element_type=jnp.float32) * scale
         valid = kp[None, :] <= pos[:, None]                  # (B, Ck)
         if window is not None:
@@ -177,7 +178,7 @@ def flash_decode(
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1)
-        pv = jnp.einsum("bkgs,bskh->bkgh", p.astype(vj.dtype), vj)
+        pv = jnp.einsum("bkgs,bksh->bkgh", p.astype(vj.dtype), vj)
         acc_new = acc * corr[..., None] + pv.astype(jnp.float32)
         return (m_new, l_new, acc_new), None
 

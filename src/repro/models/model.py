@@ -278,8 +278,14 @@ def _attn_specs(cfg, rules: ShardingRules):
 
 
 def _run_attention(p, x, cfg, rules, window, positions, cache=None,
-                   pos=None, kv_valid=None):
-    """Returns (out, (k, v)) for prefill/train or (out, new_cache) decode."""
+                   pos=None, blk=None):
+    """Returns (out, (k, v)) for prefill/train or (out, new_cache) decode.
+
+    K and V come out, and the decode cache is held, as (B, KV, S, hd) per
+    layer: the row write and the attention read agree on one layout. In
+    decode ``cache`` is every block's stacked (nb, B, KV, S_max, hd) K and
+    V and ``blk`` this block's index; one row per lane is written in place.
+    """
     B, S, D = x.shape
     hd, H, KV = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     G = H // KV
@@ -329,15 +335,17 @@ def _run_attention(p, x, cfg, rules, window, positions, cache=None,
                                   q_positions=positions,
                                   kv_positions=positions)
         out = out.reshape(B, S, H * hd)
-        return jnp.einsum("bsh,hd->bsd", out, p["wo"]), (k, v)
+        return (jnp.einsum("bsh,hd->bsd", out, p["wo"]),
+                (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)))
     # decode: single token per sequence at per-sequence positions (B,)
-    k_cache, v_cache = cache
-    S_max = k_cache.shape[1]
+    k_stack, v_stack = cache
+    S_max = k_stack.shape[3]
     pos = jnp.broadcast_to(jnp.asarray(pos), (B,))
     cos, sin = rope_tables(pos[:, None], hd, cfg.rope_theta)    # (B,1,hd/2)
     q = apply_rope(q.reshape(B, S, KV * G, hd), cos, sin) \
         .reshape(B, KV, G, hd)
-    k = apply_rope(k, cos, sin)
+    k = apply_rope(k, cos, sin)[:, 0].astype(k_stack.dtype)     # (B,KV,hd)
+    v = v[:, 0].astype(v_stack.dtype)
     tp_size = 1 if rules is None else rules.axis_size(rules.tp)
     use_cp = (rules is not None and rules.mesh is not None
               and rules.attn_mode == "context" and tp_size > 1
@@ -348,22 +356,24 @@ def _run_attention(p, x, cfg, rules, window, positions, cache=None,
         # psum merges the online-softmax stats — no cache gather/halo.
         dp_sz = max(rules.axis_size(rules.dp), 1)
         b_ax = rules.dp if B % dp_sz == 0 else None
-        cspec = rules.spec(b_ax, rules.tp, None, None)
+        cspec = rules.spec(None, b_ax, None, rules.tp, None)
         qspec = rules.spec(b_ax, None, None, None)
+        rowspec = rules.spec(b_ax, None, None)
         s_loc = S_max // tp_size
 
-        def body(q, k1, v1, kc, vc, pos):
+        def body(q, k1, v1, kc, vc, pos, blk):
             rank = jax.lax.axis_index(rules.tp)
             off = rank * s_loc
             upd = pos - off
             owned = (upd >= 0) & (upd < s_loc)
-            safe = jnp.clip(upd, 0, s_loc - 1)
-            bi = jnp.arange(q.shape[0])
-            kc = kc.at[bi, safe].set(
-                jnp.where(owned[:, None, None], k1[:, 0], kc[bi, safe]))
-            vc = vc.at[bi, safe].set(
-                jnp.where(owned[:, None, None], v1[:, 0], vc[bi, safe]))
-            acc, m, l = flash_decode(q, kc, vc, pos, window=window,
+            safe = jnp.clip(upd, 0, s_loc - 1)[:, None]
+            at = (blk, jnp.arange(q.shape[0])[:, None],
+                  jnp.arange(KV)[None, :], safe)
+            kc = kc.at[at].set(
+                jnp.where(owned[:, None, None], k1, kc[at]))
+            vc = vc.at[at].set(
+                jnp.where(owned[:, None, None], v1, vc[at]))
+            acc, m, l = flash_decode(q, kc[blk], vc[blk], pos, window=window,
                                      kpos_offset=off, return_stats=True)
             m_g = jax.lax.pmax(m, rules.tp)
             scale = jnp.exp(m - m_g)
@@ -372,39 +382,45 @@ def _run_attention(p, x, cfg, rules, window, positions, cache=None,
             out = (num / jnp.maximum(den, 1e-30)[..., None]).astype(q.dtype)
             return out, kc, vc
 
-        out, k_cache, v_cache = jax.shard_map(
+        out, k_stack, v_stack = jax.shard_map(
             body, mesh=rules.mesh,
-            in_specs=(qspec, qspec, qspec, cspec, cspec,
-                      rules.spec(b_ax)),
+            in_specs=(qspec, rowspec, rowspec, cspec, cspec,
+                      rules.spec(b_ax), P()),
             out_specs=(qspec, cspec, cspec), check_vma=False,
-        )(q, k, v, k_cache, v_cache, pos)
+        )(q, k, v, k_stack, v_stack, pos, blk)
     else:
-        k_cache = k_cache.at[jnp.arange(B), pos].set(k[:, 0])
-        v_cache = v_cache.at[jnp.arange(B), pos].set(v[:, 0])
+        # one head_dim row per (lane, kv head) at the lane's position:
+        # with the heads indexed too, the scatter's window is head_dim
+        # alone and it updates the positions-minor stack in place
+        bi, ki = jnp.arange(B)[:, None], jnp.arange(KV)[None, :]
+        k_stack = k_stack.at[blk, bi, ki, pos[:, None]].set(k)
+        v_stack = v_stack.at[blk, bi, ki, pos[:, None]].set(v)
         if rules is not None:
-            cspec = P(rules.dp, None, rules.tp, None)
-            k_cache = rules.constrain(k_cache, *cspec)
-            v_cache = rules.constrain(v_cache, *cspec)
-        out = flash_decode(q, k_cache, v_cache, pos, window=window)
+            cspec = P(None, rules.dp, rules.tp, None, None)
+            k_stack = rules.constrain(k_stack, *cspec)
+            v_stack = rules.constrain(v_stack, *cspec)
+        out = flash_decode(q, k_stack[blk], v_stack[blk], pos, window=window)
     out = out.reshape(B, 1, H * hd)
-    return jnp.einsum("bsh,hd->bsd", out, p["wo"]), (k_cache, v_cache)
+    return jnp.einsum("bsh,hd->bsd", out, p["wo"]), (k_stack, v_stack)
 
 
-def _run_attention_chunk(p, x, cfg, window, cache, positions, lane, offset,
-                         n_valid, row_valid):
+def _run_attention_chunk(p, x, cfg, window, cache, blk, positions, lane,
+                         offset, n_valid, row_valid):
     """Chunked-prefill attention: one prompt chunk of one sequence against
     its lane in the full (batch, S_max) cache.
 
-    ``row_valid`` masks the tail chunk's padding: padded rows never reach
-    the cache (masked write) and unwritten cache rows never reach the
-    scores (``kv_valid``), so a chunked prefill accumulates exactly the
-    rows a whole-prompt prefill would.
+    ``cache`` is every block's stacked (nb, B, KV, S_max, hd) K and V and
+    ``blk`` this block's index. ``row_valid`` masks the tail chunk's
+    padding: padded rows never reach the cache (masked write) and
+    unwritten cache rows never reach the scores (``kv_valid``), so a
+    chunked prefill accumulates exactly the rows a whole-prompt prefill
+    would.
     """
     B, C, D = x.shape                    # B == 1: one sequence's chunk
     hd, H, KV = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     G = H // KV
-    k_cache, v_cache = cache
-    S_max = k_cache.shape[1]
+    k_stack, v_stack = cache
+    S_max = k_stack.shape[3]
     q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(B, C, KV, G, hd)
     k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(B, C, KV, hd)
     v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(B, C, KV, hd)
@@ -415,33 +431,43 @@ def _run_attention_chunk(p, x, cfg, window, cache, positions, lane, offset,
     lane = jnp.asarray(lane, jnp.int32)
     offset = jnp.asarray(offset, jnp.int32)
 
-    def write(cbuf, new):
-        # masked in-place write at (lane, offset): padded rows keep the
-        # old cache contents (offset + C <= S_max by EngineConfig
-        # validation, so dynamic_slice never clamps/shifts the window)
-        old = jax.lax.dynamic_slice(cbuf, (lane, offset, 0, 0),
-                                    (1, C, KV, hd))
-        upd = jnp.where(row_valid[None, :, None, None],
-                        new.astype(cbuf.dtype), old)
-        return jax.lax.dynamic_update_slice(cbuf, upd, (lane, offset, 0, 0))
+    def write(stack, new):
+        # masked in-place write of the chunk's rows at (blk, lane, :,
+        # offset): padded rows keep the old cache contents (offset + C <=
+        # S_max by EngineConfig validation, so dynamic_slice never
+        # clamps/shifts the window)
+        at = (blk, lane, 0, offset, 0)
+        old = jax.lax.dynamic_slice(stack, at, (1, 1, KV, C, hd))
+        new = new.transpose(0, 2, 1, 3)[None].astype(stack.dtype)
+        upd = jnp.where(row_valid[:, None], new, old)
+        return jax.lax.dynamic_update_slice(stack, upd, at)
 
-    k_cache = write(k_cache, k)
-    v_cache = write(v_cache, v)
-    k_lane = jax.lax.dynamic_slice(k_cache, (lane, 0, 0, 0),
-                                   (1, S_max, KV, hd))
-    v_lane = jax.lax.dynamic_slice(v_cache, (lane, 0, 0, 0),
-                                   (1, S_max, KV, hd))
+    def read(stack):
+        # the lane as flash_attention takes it, (1, S_max, KV, hd)
+        at = (blk, lane, 0, 0, 0)
+        lane_kv = jax.lax.dynamic_slice(stack, at, (1, 1, KV, S_max, hd))
+        return lane_kv[0].transpose(0, 2, 1, 3)
+
+    k_stack = write(k_stack, k)
+    v_stack = write(v_stack, v)
     kv_valid = jnp.arange(S_max) < offset + n_valid
-    out = flash_attention(q, k_lane, v_lane, causal=cfg.causal,
+    out = flash_attention(q, read(k_stack), read(v_stack), causal=cfg.causal,
                           window=window, q_positions=positions,
                           kv_positions=jnp.arange(S_max), kv_valid=kv_valid)
     out = out.reshape(B, C, H * hd)
-    return jnp.einsum("bsh,hd->bsd", out, p["wo"]), (k_cache, v_cache)
+    return jnp.einsum("bsh,hd->bsd", out, p["wo"]), (k_stack, v_stack)
 
 
 def _block_body(cfg, rules, specs, bp, x, *, windows_blk, moe_tables_blk,
-                positions, phase, cache_blk=None, pos=None, chunk_ctx=None):
-    """One super-block forward. Returns (x, tallies, aux, new_cache_blk).
+                positions, phase, cache=None, blk=None, pos=None,
+                chunk_ctx=None):
+    """One super-block forward. Returns (x, tallies, aux, new_cache).
+
+    Without ``cache`` (train, whole-prompt prefill) ``new_cache`` is this
+    block's per-position states. With it (decode, chunk) ``cache`` is every
+    block's stacked states and ``blk`` this block's index: the block reads
+    its entry and writes back only what it changed, and ``new_cache`` is
+    the whole updated stack.
 
     ``chunk_ctx`` — (lane, offset, n_valid, row_valid) for the chunked-
     prefill phase: attention routes through :func:`_run_attention_chunk`
@@ -457,26 +483,31 @@ def _block_body(cfg, rules, specs, bp, x, *, windows_blk, moe_tables_blk,
             window = None
             if windows_blk is not None:
                 window = windows_blk[i]
-            cache = None if cache_blk is None else cache_blk[i]
+            st_in = None if cache is None else cache[i]
             with jax.named_scope("attention"):
                 if phase == "chunk":
                     lane, offset, n_valid, row_valid = chunk_ctx
                     h, st = _run_attention_chunk(
-                        sub["mixer"], h, cfg, window, cache, positions,
+                        sub["mixer"], h, cfg, window, st_in, blk, positions,
                         lane, offset, n_valid, row_valid)
                 else:
                     h, st = _run_attention(sub["mixer"], h, cfg, rules,
-                                           window, positions, cache=cache,
-                                           pos=pos)
+                                           window, positions, cache=st_in,
+                                           pos=pos, blk=blk)
             new_cache.append(st)
         else:
-            st_in = None if cache_blk is None else cache_blk[i]
+            st_in = None if cache is None else jax.tree.map(
+                lambda a: a[blk], cache[i])
             fn = {"mamba": ssm.mamba_seq, "mlstm": ssm.mlstm_seq,
                   "slstm": ssm.slstm_seq}[spec.mixer]
             if phase == "decode":
                 fn = {"mamba": ssm.mamba_step, "mlstm": ssm.mlstm_step,
                       "slstm": ssm.slstm_step}[spec.mixer]
             h, st = fn(sub["mixer"], h, st_in)
+            if cache is not None:
+                st = jax.tree.map(
+                    lambda a, s: jax.lax.dynamic_update_index_in_dim(
+                        a, s.astype(a.dtype), blk, 0), cache[i], st)
             new_cache.append(st)
         x = x + h
         if spec.ffn != "none":
@@ -577,14 +608,13 @@ def _scan_blocks(cfg, rules, params, x, *, phase, moe_tables, positions,
     seq_ok = (rules is not None and phase != "decode"
               and x.shape[1] % max(rules.axis_size(rules.tp), 1) == 0)
 
-    def body(x, xs):
-        bp, wb, mt, cb = xs
+    def block(x, bp, wb, mt, cache=None, blk=None):
         if seq_ok:
             x = rules.constrain(x, rules.dp, rules.tp, None)
         fn = lambda x_: _block_body(cfg, rules, specs, bp, x_,
                                     windows_blk=wb, moe_tables_blk=mt,
                                     positions=positions, phase=phase,
-                                    cache_blk=cb, pos=pos,
+                                    cache=cache, blk=blk, pos=pos,
                                     chunk_ctx=chunk_ctx)
         if rules is not None and rules.remat and phase == "train":
             x, tall, aux, nc = jax.checkpoint(fn)(x)
@@ -592,12 +622,28 @@ def _scan_blocks(cfg, rules, params, x, *, phase, moe_tables, positions,
             x, tall, aux, nc = fn(x)
         if seq_ok:
             x = rules.constrain(x, rules.dp, rules.tp, None)
-        if phase == "train":
-            nc = []        # don't materialize stacked states during training
-        return x, (tall, aux, nc)
+        return x, tall, aux, nc
 
-    xs = (params["blocks"], win, moe_tables, cache)
-    x, (tallies, aux, new_cache) = jax.lax.scan(body, x, xs)
+    xs = (params["blocks"], win, moe_tables)
+    if cache is None:
+        def body(x, xs):
+            x, tall, aux, nc = block(x, *xs)
+            if phase == "train":
+                nc = []    # don't materialize stacked states during training
+            return x, (tall, aux, nc)
+
+        x, (tallies, aux, new_cache) = jax.lax.scan(body, x, xs)
+    else:
+        # the stacked cache rides in the carry, so each block updates its
+        # rows in place (with the cache donated, in the caller's buffer)
+        def body(carry, xs):
+            x, cache = carry
+            blk, xs = xs
+            x, tall, aux, cache = block(x, *xs, cache=cache, blk=blk)
+            return (x, cache), (tall, aux)
+
+        (x, new_cache), (tallies, aux) = jax.lax.scan(
+            body, (x, cache), (jnp.arange(nb, dtype=jnp.int32), xs))
     # tallies (nb, m, E+1) → (n_moe_layers, E+1): per-layer logical-expert
     # routing counts plus a final capacity-dropped-assignment column
     # (see moe_layer); aux summed
@@ -661,6 +707,8 @@ def prefill_chunk_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
     the cache write, the attention scores and the MoE tallies, so the
     final chunk's logits and cache state match a whole-prompt prefill.
     Logits are only meaningful on the chunk that completes the prompt.
+    Only the chunk's rows of ``lane`` change, so a caller that donates
+    ``cache`` gets it updated in place.
     """
     _, specs = block_layout(cfg)
     if any(s.mixer != "attn" for s in specs):
@@ -692,7 +740,11 @@ def prefill_chunk_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
 
 
 def decode_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
-    """(params, token (B,1), cache, pos) → (logits, new cache, tallies)."""
+    """(params, token (B,1), cache, pos) → (logits, new cache, tallies).
+
+    The new cache differs from ``cache`` in one row per lane and layer, so
+    a caller that donates ``cache`` gets it updated in place.
+    """
 
     def decode_step(params, token, cache, pos, moe_tables=None):
         """``pos``: (B,) per-sequence positions (continuous batching)."""
@@ -710,12 +762,14 @@ def decode_fn(cfg: ArchConfig, rules: Optional[ShardingRules] = None):
 
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
                rules: Optional[ShardingRules] = None, dtype=jnp.bfloat16):
-    """Stacked per-block cache pytree matching the scan layout."""
+    """Stacked per-block cache pytree matching the scan layout: a list over
+    the block's positions; an attention position holds K and V as
+    (n_blocks, batch, kv_heads, max_seq, head_dim)."""
     nb, specs = block_layout(cfg)
     per_pos = []
     for spec in specs:
         if spec.mixer == "attn":
-            shape = (nb, batch, max_seq, cfg.n_kv_heads, cfg.hd)
+            shape = (nb, batch, cfg.n_kv_heads, max_seq, cfg.hd)
             per_pos.append((jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)))
         elif spec.mixer == "mamba":
             st = ssm.mamba_state_init(batch, cfg.d_model,

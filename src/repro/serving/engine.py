@@ -212,12 +212,15 @@ class Engine:
                 cfg, rules, perm=self._perm,
                 n_slots=self.n_slots) if cfg.is_moe else None
         self._prefill = jax.jit(prefill_fn(cfg, rules))
-        self._decode = jax.jit(decode_fn(cfg, rules))
+        # the step programs take the cache donated (argument 2) and update
+        # it in place; each call's output cache replaces ``self.cache``
+        self._decode = jax.jit(decode_fn(cfg, rules), donate_argnums=(2,))
         # scheduling + memory: registered scheduler, paged KV admission
         self.scheduler = get_scheduler(config.scheduler.name)
         self._sched_cfg = config.scheduler
         self._chunk = config.scheduler.prefill_chunk
-        self._prefill_chunk = (jax.jit(prefill_chunk_fn(cfg, rules))
+        self._prefill_chunk = (jax.jit(prefill_chunk_fn(cfg, rules),
+                                       donate_argnums=(2,))
                                if self._chunk > 0 else None)
         self.kv = PagedKVCache(config.kv)
         self._prefill_streak = 0
@@ -572,11 +575,12 @@ class Engine:
     def _insert_cache(self, slot: int, pre_cache) -> None:
         """Insert a prefilled (batch-1) cache pytree into engine slot."""
         def ins(ec, pc):
-            if pc.ndim >= 3 and ec.shape[2] != pc.shape[2]:
-                pad = [(0, 0)] * pc.ndim
-                pad[2] = (0, ec.shape[2] - pc.shape[2])
-                pc = jnp.pad(pc, pad)
-            return ec.at[:, slot].set(pc[:, 0].astype(ec.dtype))
+            # a KV leaf holds the prompt's positions only: pad them to
+            # max_seq (recurrent states match the engine's shape already)
+            pad = [(0, 0), (0, 0)] + [(0, e - n) for e, n in
+                                      zip(ec.shape[2:], pc.shape[2:])]
+            return ec.at[:, slot].set(jnp.pad(pc, pad)[:, 0]
+                                      .astype(ec.dtype))
         self.cache = jax.tree.map(ins, self.cache, pre_cache)
 
     def _release(self, lane: int) -> None:

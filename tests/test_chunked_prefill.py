@@ -83,10 +83,22 @@ class TestModelLevel:
         np.testing.assert_allclose(np.asarray(tal_c), np.asarray(tal_w),
                                    atol=0)
 
+    def test_cache_matches_whole_prompt_prefill(self):
+        # cache leaves are (layers, lane, kv_heads, seq, head_dim); the
+        # lane's prompt rows are the ones whole-prompt prefill emits
+        P = self.prompt.shape[1]
+        _, want, _ = prefill_fn(self.cfg)(
+            self.params, {"tokens": jnp.asarray(self.prompt)}, self.mt)
+        _, cache, _ = _chunked_run(self.cfg, self.params, self.cache,
+                                   self.prompt, 4, 1, self.mt)
+        for got, w in zip(jax.tree.leaves(cache), jax.tree.leaves(want)):
+            assert np.array_equal(np.asarray(got)[:, 1, :, :P],
+                                  np.asarray(w)[:, 0])
+
     def test_other_lane_untouched(self):
         _, cache, _ = _chunked_run(self.cfg, self.params, self.cache,
                                    self.prompt, 4, 0, self.mt)
-        # cache leaves are (layers, lane, seq, kv_heads, head_dim)
+        # cache leaves are (layers, lane, kv_heads, seq, head_dim)
         for before, after in zip(jax.tree.leaves(self.cache),
                                  jax.tree.leaves(cache)):
             assert np.array_equal(np.asarray(before)[:, 1],

@@ -5,6 +5,8 @@ forward/train step on CPU asserting output shapes + no NaNs; decoder archs
 also run prefill + decode.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,8 @@ import pytest
 
 from repro.configs import ALL_ARCHS, EXTRA_ARCHS, get_smoke
 from repro.models import (block_layout, decode_fn, init_cache, init_params,
-                          loss_fn, make_moe_tables, prefill_fn)
+                          loss_fn, make_moe_tables, prefill_chunk_fn,
+                          prefill_fn)
 from repro.models import ssm
 from repro.models.flash import flash_attention, flash_decode
 from repro.training import adamw_init, adamw_update
@@ -114,6 +117,97 @@ def test_decode_matches_prefill_logits():
                                 jnp.full((B,), t, jnp.int32), None)
     np.testing.assert_allclose(np.asarray(logits_d), np.asarray(logits_p),
                                atol=0.75, rtol=0.05)  # bf16 path tolerance
+
+
+#: arch -> (tolerance as a share of each array's largest magnitude,
+#: blocks). Two blocks, so each block must find its own entry of the
+#: stacked cache; jamba's mamba states cover that for recurrent states.
+#: The xLSTM's recurrent step and its sequence form drift apart with depth
+#: (float32: 2% of a logit at one block, 21% at two), so it runs one.
+_CARRIED = {"granite-moe-3b-a800m": (1e-5, 2),
+            "jamba-1.5-large-398b": (1e-5, 2), "xlstm-350m": (0.05, 1)}
+
+
+def _close(a, b, tol):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    np.testing.assert_allclose(a, b, rtol=0,
+                               atol=tol * max(float(np.abs(b).max()), 1.0))
+
+
+@pytest.mark.parametrize("arch", sorted(_CARRIED))
+def test_carried_cache_matches_prefill(arch):
+    """The step programs as the engine jits them, the cache donated: a
+    prompt teacher-forced through decode, and (where every mixer is
+    attention) fed in chunks, leaves the logits and the cache that
+    whole-prompt prefill gives, K and V stored (blocks, lanes, kv heads,
+    positions, head_dim). Float32 weights and cache, so the comparison is
+    of the computation and not of bfloat16 rounding."""
+    tol, blocks = _CARRIED[arch]
+    cfg = get_smoke(arch)
+    cfg = dataclasses.replace(
+        cfg, n_layers=blocks * len(block_layout(cfg)[1]))
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    mt = make_moe_tables(cfg, None)
+    B, S, S_max = 2, 8, 16
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab, (B, S)), jnp.int32)
+    logits_p, cache_p, _ = prefill_fn(cfg)(params, {"tokens": tokens}, mt)
+    _, specs = block_layout(cfg)
+
+    def check_cache(cache):
+        for spec, got, want in zip(specs, cache, cache_p):
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                g = np.asarray(g)
+                if spec.mixer == "attn":
+                    assert g.shape == (w.shape[0], B, cfg.n_kv_heads, S_max,
+                                       cfg.hd)
+                    assert not g[:, :, :, S:].any()    # no row past the prompt
+                    g = g[:, :, :, :S]
+                _close(g, w, tol)
+
+    df = jax.jit(decode_fn(cfg), donate_argnums=(2,))
+    cache = init_cache(cfg, B, S_max, dtype=jnp.float32)
+    for t in range(S):
+        given = jax.tree.leaves(cache)
+        logits_d, cache, _ = df(params, tokens[:, t:t + 1], cache,
+                                jnp.full((B,), t, jnp.int32), mt)
+        assert all(a.is_deleted() for a in given)      # updated in place
+    _close(logits_d, logits_p, tol)
+    check_cache(cache)
+
+    if any(s.mixer != "attn" for s in specs):
+        return                        # chunked prefill refuses recurrences
+    cf = jax.jit(prefill_chunk_fn(cfg), donate_argnums=(2,))
+    cache, C = init_cache(cfg, B, S_max, dtype=jnp.float32), 3
+    for lane in range(B):
+        for off in range(0, S, C):
+            n_valid = min(C, S - off)
+            buf = np.zeros((1, C), np.int32)
+            buf[0, :n_valid] = tokens[lane, off:off + n_valid]
+            logits_c, cache, _ = cf(params, jnp.asarray(buf), cache, lane,
+                                    off, n_valid, mt)
+        _close(logits_c[0], logits_p[lane], tol)
+    check_cache(cache)
+
+
+def test_decode_writes_one_row_per_lane():
+    """A decode step changes exactly one position of each lane's K and V,
+    the lane's own, in every layer, and leaves every other row as it was."""
+    cfg = get_smoke("granite-moe-3b-a800m")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    B, S_max = 3, 16
+    pos = np.array([3, 0, 11])
+    dirty = jax.tree.map(lambda c: jnp.asarray(np.random.default_rng(1).normal(
+        size=c.shape), c.dtype), init_cache(cfg, B, S_max))
+    before = [np.asarray(a) for a in jax.tree.leaves(dirty)]
+    _, cache, _ = jax.jit(decode_fn(cfg), donate_argnums=(2,))(
+        params, jnp.ones((B, 1), jnp.int32), dirty, jnp.asarray(pos),
+        make_moe_tables(cfg, None))
+    for old, new in zip(before, jax.tree.leaves(cache)):
+        changed = (np.asarray(new) != old).any(axis=(2, 4))   # (nb, B, S)
+        want = np.zeros_like(changed)
+        want[:, np.arange(B), pos] = True
+        np.testing.assert_array_equal(changed, want)
 
 
 def test_gemma3_window_pattern():
@@ -224,13 +318,13 @@ def test_flash_decode_per_sequence_positions():
     B, S_max, KV, G, hd = 3, 32, 2, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd))
-    kc = jax.random.normal(ks[1], (B, S_max, KV, hd))
-    vc = jax.random.normal(ks[2], (B, S_max, KV, hd))
+    kc = jax.random.normal(ks[1], (B, KV, S_max, hd))
+    vc = jax.random.normal(ks[2], (B, KV, S_max, hd))
     pos = jnp.array([5, 17, 31])
     out = flash_decode(q, kc, vc, pos, kv_chunk=8)
     for b in range(B):
-        sc = jnp.einsum("kgh,skh->kgs", q[b], kc[b]) / np.sqrt(hd)
+        sc = jnp.einsum("kgh,ksh->kgs", q[b], kc[b]) / np.sqrt(hd)
         sc = jnp.where((jnp.arange(S_max) <= pos[b])[None, None], sc, -1e30)
-        ref = jnp.einsum("kgs,skh->kgh", jax.nn.softmax(sc, -1), vc[b])
+        ref = jnp.einsum("kgs,ksh->kgh", jax.nn.softmax(sc, -1), vc[b])
         np.testing.assert_allclose(np.asarray(out[b]), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)

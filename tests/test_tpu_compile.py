@@ -6,13 +6,16 @@ Pallas kernels at the widths of the supported MoEs (granite-moe-3b-a800m
 whole; qwen3-moe-235b-a22b and deepseek-v3 with 8 experts, one chip's
 share of an expert-parallel deployment), granite's ragged dispatch around
 the ragged kernel, and the full-width granite decode step, for one chip of
-a ``v5e:2x2`` topology.
+a ``v5e:2x2`` topology; and they check that granite's decode and chunk
+steps, the cache donated, update it in place.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and under
 pytest-xdist every worker imports every test file. Where it cannot be
 described the tests skip.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -24,7 +27,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get
 from repro.core import default_slots_per_rank
 from repro.kernels import ops
-from repro.models import decode_fn, init_cache, init_params, make_moe_tables
+from repro.models import (decode_fn, init_cache, init_params,
+                          make_moe_tables, prefill_chunk_fn)
 from repro.models import moe
 from repro.models.sharding import ShardingRules
 
@@ -107,23 +111,24 @@ def test_ragged_dispatch_compiles_for_v5e(one_chip):
     assert "scatter-add" not in text
 
 
-def test_granite_decode_step_fits_v5e(one_chip):
-    """The served step at published width, with the expert slots the
-    vibe_r slot budget grows to on one 16 GB chip (8 ranks x the policy
-    default), 8 lanes x 2048 cached positions."""
+def _granite_step_args(sharding, B, S):
+    """Shapes of granite's parameters at published width, with the expert
+    slots the vibe_r slot budget grows to on one 16 GB chip (8 ranks x the
+    policy default: 48 a layer), its placement tables and a cache of ``B``
+    lanes x ``S`` positions, all on ``sharding``."""
     cfg = get("granite-moe-3b-a800m")
-    B, S, G = 8, 2048, 8
+    G = 8
     n_slots = G * default_slots_per_rank(cfg.n_experts, G)
 
     def place(path, a):
         shape = a.shape
         if path[-1].key in ("w1", "w2", "w3"):
             shape = shape[:1] + (n_slots,) + shape[2:]
-        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=sharding)
 
     def shapes(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
+            a.shape, a.dtype, sharding=sharding), tree)
 
     params = jax.tree_util.tree_map_with_path(place, jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
@@ -131,6 +136,14 @@ def test_granite_decode_step_fits_v5e(one_chip):
     tables = shapes(jax.eval_shape(lambda: make_moe_tables(
         cfg, None, perm=perm, n_slots=n_slots, r_max=G)))
     cache = shapes(jax.eval_shape(lambda: init_cache(cfg, B, S)))
+    return cfg, params, tables, cache
+
+
+def test_granite_decode_step_fits_v5e(one_chip):
+    """The served step at published width, 8 lanes x 2048 cached
+    positions."""
+    B, S = 8, 2048
+    cfg, params, tables, cache = _granite_step_args(one_chip, B, S)
     tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(decode_fn(cfg)).lower(params, tok, cache, pos,
@@ -139,3 +152,70 @@ def test_granite_decode_step_fits_v5e(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"decode step needs {total} B"
+
+
+#: ops that name or pass on buffers without moving their bytes
+_NO_MOVE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "conditional", "call", "constant", "opt-barrier"}
+_COMP = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_INSTR = re.compile(r"^\s+(?:ROOT )?%(\S+) = (.+?) ([a-z][\w-]*)\((.*)$")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+_NESTED = re.compile(r"(?:calls|to_apply)=%([\w.-]+)")
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2}
+
+
+def _top_level_arrays(hlo: str):
+    """(instruction, op, dims, bytes) of every array an instruction outputs
+    outside fusion bodies and reducers: the buffers the program writes."""
+    comps, nested, cur = {}, set(), None
+    for line in hlo.splitlines():
+        m = _COMP.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        m = _INSTR.match(line)
+        if m and cur is not None:
+            cur.append(m.groups()[:3])
+            nested.update(_NESTED.findall(m.group(4)))
+    for comp, instrs in comps.items():
+        if comp in nested:
+            continue
+        for name, out, op in instrs:
+            for dtype, dims in _ARRAY.findall(out):
+                dims = tuple(int(d) for d in dims.split(",") if d)
+                yield name, op, dims, np.prod(dims) * _BYTES.get(dtype, 4)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_granite_step_updates_cache_in_place(one_chip, program):
+    """At the served cell's shapes (32 lanes x 1024 positions, 48 expert
+    slots a layer), the step program jitted with the cache donated, as the
+    engine jits it, writes its output cache into the input's buffers and
+    moves no layer's K or V: every op that writes a buffer as large as one
+    layer's K is an update of the carried (blocks, lanes, kv heads,
+    positions, head_dim) stack, and none is a copy."""
+    B, S, C = 32, 1024, 256
+    cfg, params, tables, cache = _granite_step_args(one_chip, B, S)
+
+    def i32(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "decode_step":
+        fn, args = decode_fn(cfg), (params, i32((B, 1)), cache, i32((B,)),
+                                    tables)
+    else:
+        fn, args = prefill_chunk_fn(cfg), (params, i32((1, C)), cache,
+                                           i32(()), i32(()), i32(()), tables)
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    leaves = jax.tree.leaves(cache)
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in leaves)
+    stack = leaves[0].shape
+    assert stack == (cfg.n_layers, B, cfg.n_kv_heads, S, cfg.hd)
+    layer_bytes = np.prod(stack[1:]) * 2
+    big = [(name, op, dims) for name, op, dims, nbytes
+           in _top_level_arrays(compiled.as_text())
+           if op not in _NO_MOVE and nbytes >= layer_bytes]
+    assert big, "no update of the cache found"
+    moved = [b for b in big if b[2] != stack or "copy" in b[1]]
+    assert not moved, f"ops that move a layer's K or V: {moved}"
