@@ -33,20 +33,13 @@ def main(argv=None) -> int:
     ap.add_argument("--control", type=int, default=1)
     args = ap.parse_args(argv)
     from chipbench import run
-    spec = run.load_cell(json.loads((ROOT / "BENCHMARK.json").read_text()),
-                         args.workload)
-    from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    if jax.devices()[0].platform != "tpu":
-        print("calibrate: needs a TPU", file=sys.stderr)
+    spec = run.start(args.workload)
+    if spec is None:
         return 1
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         res = run.run_cell(spec, seed, args.seconds, False, t_start=t0,
-                           control=bool(args.control), with_readings=True,
+                           control=bool(args.control), detail=True,
                            log=lambda s: print(s, file=sys.stderr,
                                                flush=True))
         r = res["readings"]

@@ -15,7 +15,7 @@ from pathlib import Path
 
 from chipbench.reference import Arch
 
-__all__ = ["TokenCost", "token_cost", "peak_flops"]
+__all__ = ["TokenCost", "token_cost", "from_config", "peak_flops"]
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -41,6 +41,12 @@ class TokenCost:
 
 def token_cost(arch: Arch) -> TokenCost:
     return TokenCost(arch)
+
+
+def from_config(config: dict, arch: Arch) -> TokenCost:
+    """The module's entry (``chipbench.harness.model_of``): the count of a
+    configuration file's model, whose sizes the reference module gave."""
+    return token_cost(arch)
 
 
 def peak_flops(device_kind: str) -> float:

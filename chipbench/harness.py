@@ -16,17 +16,23 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib.util
+import sys
 import time
-from typing import Dict, List, Optional
+from pathlib import Path
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
-from chipbench import flops, loadgen
-from chipbench.reference import Arch
+from chipbench import loadgen
 
-__all__ = ["Spans", "StepRecord", "Window", "build_engine", "warm_up",
+__all__ = ["Spans", "StepRecord", "Window", "Model", "load_module",
+           "model_of", "arch_config", "build_engine", "warm_up",
            "run_window"]
+
+#: the benchmark's own directory: modules a configuration names lie in it
+HERE = Path(__file__).resolve().parent
 
 #: request ids of warm-up requests; the window's start above them
 _WARM_BASE = 1 << 40
@@ -78,29 +84,114 @@ class Window:
     steps: List[StepRecord]
     spans: Spans
     compiles: Dict[str, int]
+    #: host time at which each ``engine.step`` call began, by the engine's
+    #: step count before it (an idle call is overwritten by the next)
+    step_start: Dict[int, float]
 
 
-def arch_config(model: dict, arch: Arch, name: str):
+def load_module(path: Path, name: str):
+    """The Python file at ``path`` as the module ``name``, which it keeps
+    in ``sys.modules`` (where a dataclass in it looks itself up): loaded
+    once in a process, or taken from an import of the same file."""
+    path = Path(path).resolve()
+    mod = sys.modules.get(name)
+    if mod is not None and getattr(mod, "__file__", None) \
+            and Path(mod.__file__).resolve() == path:
+        return mod
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    parent, _, child = name.rpartition(".")
+    if parent in sys.modules:             # as an import would bind it
+        setattr(sys.modules[parent], child, mod)
+    return mod
+
+
+#: the modules a configuration file names, and those it gets without
+MODULE_KEYS = {"reference": "reference.py", "flops": "flops.py"}
+
+
+def _named_module(config: dict, key: str, base: Path):
+    rel = config.get(key, MODULE_KEYS[key])
+    path = (base / rel).resolve()
+    if path.suffix != ".py" or base.resolve() not in path.parents \
+            or not path.is_file():
+        raise ValueError(f"{config['name']}: {key!r} names {rel!r}, which "
+                         f"is no Python file under {base}")
+    # named as the package names it: the default files are the modules
+    # that ``import chipbench.reference`` gives, so a process holds one
+    return load_module(path, ".".join(
+        (base.name, *path.relative_to(base.resolve()).with_suffix("").parts)))
+
+
+@dataclasses.dataclass(frozen=True)
+class Model:
+    """What a configuration file selects on the benchmark's side."""
+
+    arch: Any          # the reference module's sizes of the model
+    reference: type    # ``reference(arch, seed)``: ``.arch``, ``.logits``
+    cost: Any          # FLOPs: ``.tokens(n, ctx_sum)``, ``.unembed``
+
+
+def model_of(config: dict, base: Path = HERE) -> Model:
+    """The cell's sizes, reference class and FLOP count, from the modules
+    under ``base`` that the file names (``"reference"``, ``"flops"``;
+    :data:`MODULE_KEYS` where it names none). The sizes have one
+    source, the reference module's ``from_config(config)``; the FLOP
+    module's ``from_config(config, arch)`` counts on them, and either may
+    read the rest of the file."""
+    ref = _named_module(config, "reference", base)
+    arch = ref.from_config(config)
+    fl = _named_module(config, "flops", base)
+    return Model(arch, ref.Reference, fl.from_config(config, arch))
+
+
+def arch_config(config: dict, arch):
+    """The program's ``ArchConfig``: derived from the sizes, then the
+    file's ``"program"`` group laid over them, key by key."""
     from repro.configs.base import ArchConfig
-    return ArchConfig(
-        name=name, family="moe", n_layers=arch.n_layers,
+    derived = dict(
+        name=config["name"], family="moe", n_layers=arch.n_layers,
         d_model=arch.d_model, n_heads=arch.n_heads,
         n_kv_heads=arch.n_kv_heads, d_ff=0, vocab=arch.vocab,
         head_dim=arch.head_dim, n_experts=arch.n_experts, top_k=arch.top_k,
         moe_d_ff=arch.d_expert, moe_every=1, mlp_gated=True,
         rope_theta=arch.rope_theta, norm_eps=arch.norm_eps,
-        tie_embeddings=arch.tied, source=model.get("source", ""))
+        tie_embeddings=arch.tied, source=config["model"].get("source", ""))
+    program = config.get("program", {})
+    unknown = set(program) - {f.name for f in dataclasses.fields(ArchConfig)}
+    if unknown:
+        raise ValueError(f"{config['name']}: 'program' keys "
+                         f"{sorted(unknown)} are not fields of ArchConfig")
+    return ArchConfig(**{**derived, **program})
 
 
-def build_engine(config: dict, arch: Arch, weight_seed: int):
-    """The engine of a configuration file, weights drawn from the seed."""
-    from repro.core import (DriftConfig, ViBEConfig, ViBEController,
-                            get_policy, make_cluster)
-    from repro.launch.serve import derive_slot_budget
+def build_engine(config: dict, arch, weight_seed: int):
+    """The engine of a configuration file, weights drawn from the seed;
+    with ``"policy": null`` it has no placement controller or cluster."""
     from repro.serving import Engine, EngineConfig, SchedulerConfig
 
     eng = config["engine"]
-    cfg = arch_config(config["model"], arch, config["name"])
+    cfg = arch_config(config, arch)
+    controller = cluster = None
+    if eng["policy"] is not None:
+        controller, cluster = _controller(config["name"], eng, cfg)
+    econfig = EngineConfig(
+        max_batch=eng["max_batch"], max_seq=eng["max_seq"], moe_impl="ragged",
+        seed=weight_seed, weighted_routing=True,
+        scheduler=SchedulerConfig(name=eng["scheduler"],
+                                  prefill_chunk=eng["prefill_chunk"]))
+    return Engine(cfg, econfig, controller=controller, cluster=cluster)
+
+
+def _controller(name: str, eng: dict, cfg):
+    """The placement controller and emulated cluster an engine group
+    states."""
+    from repro.core import (DriftConfig, ViBEConfig, ViBEController,
+                            get_policy, make_cluster)
+    from repro.launch.serve import derive_slot_budget
+
     n_moe, n_slots = cfg.n_layers, cfg.n_experts
     ranks = eng["ranks"]
     cluster = make_cluster(ranks, eng["regime"], d_model=cfg.d_model,
@@ -113,7 +204,7 @@ def build_engine(config: dict, arch: Arch, weight_seed: int):
         # "auto" sizes slots from free device memory, which no cell may
         # leave to chance: a cell states its budget ("default" or a number)
         if eng["slots_per_rank"] == "auto":
-            raise ValueError(f"{config['name']}: slots_per_rank must be "
+            raise ValueError(f"{name}: slots_per_rank must be "
                              "'default' or a number of slots per rank")
         budget = derive_slot_budget(ranks, cfg.n_experts,
                                     expert_bytes * n_moe, 0,
@@ -123,12 +214,7 @@ def build_engine(config: dict, arch: Arch, weight_seed: int):
         ViBEConfig(policy=eng["policy"], adaptive=True,
                    drift=DriftConfig(**eng["drift"]),
                    expert_bytes=expert_bytes, slot_budget=budget))
-    econfig = EngineConfig(
-        max_batch=eng["max_batch"], max_seq=eng["max_seq"], moe_impl="ragged",
-        seed=weight_seed, weighted_routing=True,
-        scheduler=SchedulerConfig(name=eng["scheduler"],
-                                  prefill_chunk=eng["prefill_chunk"]))
-    return Engine(cfg, econfig, controller=controller, cluster=cluster)
+    return controller, cluster
 
 
 def _request(p: loadgen.Planned, arrival: float):
@@ -165,8 +251,9 @@ class CompileCounter:
 
 
 def run_window(engine, plan: List[loadgen.Planned], seconds: float,
-               arch: Arch, spans: Spans, counter: CompileCounter) -> Window:
-    cost = flops.token_cost(arch)
+               cost, spans: Spans, counter: CompileCounter) -> Window:
+    """Offer ``plan`` for ``seconds``; ``cost`` counts each step's model
+    FLOPs (:attr:`Model.cost`)."""
     st = engine.stats
     B = engine.max_batch
     t0 = time.perf_counter()
@@ -174,6 +261,7 @@ def run_window(engine, plan: List[loadgen.Planned], seconds: float,
     reqs = {p.req_id: ReqTrace(p.req_id, t0 + p.due, p.prompt_len,
                                p.output_len) for p in plan}
     steps: List[StepRecord] = []
+    step_start: Dict[int, float] = {}
     nxt = 0
     counter.armed = True
     while True:
@@ -196,10 +284,12 @@ def run_window(engine, plan: List[loadgen.Planned], seconds: float,
         pos_before = np.array(engine.pos)
         c0, d0 = st.chunk_steps, st.decode_steps
         p0, tok0 = st.prefill_steps, st.prefill_tokens
+        n_steps = st.steps
         with spans.span("engine.step"):
             t_s = time.perf_counter()
             busy = engine.step()
             t_e = time.perf_counter()
+        step_start[n_steps] = t_s
         if not busy:
             wake = end if nxt >= len(plan) else min(end, t0 + plan[nxt].due)
             with spans.span("idle.wait"):
@@ -242,4 +332,5 @@ def run_window(engine, plan: List[loadgen.Planned], seconds: float,
                 rt.finished = rt.finished or last
     t1 = time.perf_counter()
     counter.armed = False
-    return Window(t0, t1, reqs, steps, spans, dict(counter.counts))
+    return Window(t0, t1, reqs, steps, spans, dict(counter.counts),
+                  step_start)

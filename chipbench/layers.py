@@ -30,16 +30,14 @@ module of each device op (``jit_decode_step``, ``jit_prefill_chunk``,
 
 Device and host times are read on the busiest chip, as ``trace.py`` does.
 
-One cell, traced, with the program's spans on (``--spans 1``) or off::
+One cell, traced as ``run.py --trace 1`` runs it, with the program's
+spans on (``--spans 1``, as the benchmark has them) or off::
 
     python3 chipbench/layers.py --workload granite-3b.chat --seed 7 \\
         --seconds 50 --spans 1
 
-prints one JSON line: the cell's metrics as ``run.py`` reads them (both
-lists), the numbers above, ``queue_wait_ms`` (p90 over submitted requests
-of the start of the ``engine.step`` call that admitted it less its submit
-time; one not admitted by the window's end enters at its age) and the
-breakdown. It checks no served token: ``run.py`` is the benchmark.
+prints ``run.py``'s result line (its ``per_layer`` metrics, the check)
+with the check's readings and this whole reduction under ``layers``.
 """
 
 from __future__ import annotations
@@ -58,8 +56,7 @@ if __name__ == "__main__":
 from chipbench import xplane  # noqa: E402
 from chipbench.trace import SPANS, WINDOW, _Busy, _union  # noqa: E402
 
-__all__ = ["SCOPES", "PHASES", "reduce_layers", "queue_wait_ms",
-           "traced_run", "main"]
+__all__ = ["SCOPES", "PHASES", "reduce_layers", "queue_wait_ms", "main"]
 
 SCOPES = ("embed", "attention", "moe", "moe/router", "moe/dispatch",
           "moe/ffn", "moe/combine", "unembed", "sample")
@@ -284,82 +281,9 @@ def queue_wait_ms(window, records, step_start: Dict[int, float]):
     return float(np.percentile(waits, 90)) if waits else None
 
 
-def traced_run(spec: dict, seed: int, seconds: float, spans: bool,
-               t_start: Optional[float] = None) -> dict:
-    """Build the cell's engine, warm it up, and serve one window under the
-    profiler with the program's spans on or off; the result line."""
-    import shutil
-    import time
-
-    import jax
-    from chipbench import flops, harness, loadgen, trace
-    from chipbench import run as run_mod
-    from chipbench.reference import arch_from_config
-    try:
-        from repro.serving import tracing
-    except ImportError:                      # a program without spans
-        tracing = None
-    t_start = time.perf_counter() if t_start is None else t_start
-    arch = arch_from_config(spec["config"]["model"])
-    weight_seed = seed % (1 << 31)
-    engine = harness.build_engine(spec["config"], arch, weight_seed)
-    counter = harness.CompileCounter()
-    harness.warm_up(engine)
-    plan = loadgen.plan_requests(spec["mix"], seed, seconds,
-                                 id_base=weight_seed * (1 << 20) + 1)
-    step_start: Dict[int, float] = {}
-    step = engine.step
-
-    def timed_step():
-        step_start[engine.stats.steps] = time.perf_counter()
-        return step()
-    engine.step = timed_step
-    out_dir = run_mod.OUT / "layers"
-    shutil.rmtree(out_dir, ignore_errors=True)
-    jax.profiler.start_trace(str(out_dir))
-    if tracing is not None:
-        tracing.enable(spans)
-    setup_s = time.perf_counter() - t_start
-    try:
-        with jax.profiler.TraceAnnotation("window"):
-            window = harness.run_window(engine, plan, seconds, arch,
-                                        harness.Spans(True), counter)
-    finally:
-        if tracing is not None:
-            tracing.enable(False)
-        jax.profiler.stop_trace()
-    path = trace.find_trace(out_dir)
-    reduced = trace.reduce_trace(path) if path else None
-    layers = reduce_layers(path) if path else None
-    shutil.rmtree(out_dir, ignore_errors=True)
-    dev = jax.devices()[0]
-    peak = flops.peak_flops(dev.device_kind) if dev.platform == "tpu" \
-        else float("nan")
-    view = run_mod.RunView(window, setup_s, peak, reduced)
-    metrics = {}
-    for m in spec["end_to_end"] + spec["per_layer"]:
-        v = run_mod._reader(m["name"])(view)
-        if v is not None:
-            metrics[m["name"]] = float(v)
-    metrics.update({k: v for k, v in (layers or {}).get("layers", {}).items()
-                    if v is not None})
-    qw = queue_wait_ms(window, engine.records, step_start)
-    if qw is not None:
-        metrics["queue_wait_ms"] = qw
-    return {
-        "seed": seed, "spans": bool(spans),
-        "device": {"platform": dev.platform, "kind": dev.device_kind,
-                   "count": len(jax.devices())},
-        "compiles_in_window": window.compiles, "metrics": metrics,
-        "busy_s": (reduced or {}).get("busy_s"),
-        "window_s": (reduced or {}).get("window_s"),
-        "breakdown": {"device_ops": (reduced or {}).get("device_ops"),
-                      **{k: v for k, v in (layers or {}).items()
-                         if k not in ("layers", "window_s")}},
-    }
-
-
 def main(argv=None) -> int:
+    """``run.py --trace 1`` with the program's spans on or off, and the
+    whole reduction above in its line."""
     import argparse
     import json
     import time
@@ -372,23 +296,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--spans", type=int, choices=(0, 1), default=1)
     args = ap.parse_args(argv)
-    run_mod._paths()
-    spec = run_mod.load_cell(json.loads(
-        (run_mod.ROOT / "BENCHMARK.json").read_text()), args.workload)
-    from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    devs = jax.devices()
-    if devs[0].platform != "tpu" or len(devs) < spec["cell"]["chips"]:
-        print(f"chipbench: {args.workload} needs {spec['cell']['chips']} "
-              f"TPU chip(s); JAX found {len(devs)} {devs[0].platform} "
-              "device(s)", file=sys.stderr)
+    spec = run_mod.start(args.workload)
+    if spec is None:
         return 1
-    result = traced_run(spec, args.seed, args.seconds, bool(args.spans),
-                        t_start)
-    print(json.dumps({"workload": args.workload, **result}), flush=True)
+    result = run_mod.run_cell(
+        spec, args.seed, args.seconds, True, t_start,
+        log=lambda s: print(s, file=sys.stderr, flush=True), detail=True,
+        step_spans=bool(args.spans))
+    print(json.dumps({"workload": args.workload, "seed": args.seed,
+                      "spans": bool(args.spans), **result}), flush=True)
     return 0
 
 

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Arch", "arch_from_config", "Reference"]
+__all__ = ["Arch", "arch_from_config", "from_config", "Reference"]
 
 _Q_BLOCK = 512
 _F8 = jnp.float8_e4m3fn
@@ -71,6 +71,12 @@ def arch_from_config(model: dict) -> Arch:
         rope_theta=float(model["rope_theta"]),
         norm_eps=float(model["rms_norm_eps"]),
         tied=bool(model["tie_word_embeddings"]))
+
+
+def from_config(config: dict) -> Arch:
+    """The module's entry (``chipbench.harness.model_of``): the sizes of a
+    whole configuration file, which here are its ``model`` group's."""
+    return arch_from_config(config["model"])
 
 
 # -- weights from the seed ---------------------------------------------------
@@ -215,12 +221,17 @@ class Reference:
             del w
         head = (embed.T if arch.tied
                 else _dense(self._head_key, arch.d_model, arch.vocab))
+        # row counts padded to a power of two with copies of the last row
+        # (which leave the control's one scale as it is): few programs,
+        # where each count of served tokens would compile its own
+        idx = [np.pad(r, (0, max(64, 1 << (len(r) - 1).bit_length())
+                          - len(r)), mode="edge") for r in rows]
         out: Dict[str, List[np.ndarray]] = {}
         for p in precs:
             out[p] = [np.asarray(_mm("sd,dv->sv",
-                                     _rms(x[jnp.asarray(r)], arch.norm_eps),
-                                     head, p == "fp8"))
-                      for x, r in zip(xs[p], rows)]
+                                     _rms(x[jnp.asarray(i)], arch.norm_eps),
+                                     head, p == "fp8"))[:len(r)]
+                      for x, i, r in zip(xs[p], idx, rows)]
         return out
 
 

@@ -6,7 +6,9 @@
 The cell (``workloads`` in ``BENCHMARK.json``) names a configuration file
 and a traffic mix under ``chipbench/``; the metrics it reports are the
 entries of ``end_to_end`` (``--trace 0``) or ``per_layer`` (``--trace 1``)
-that list it, each read by ``chipbench/metrics/<name>.py``. The last line
+that list it, each read by ``chipbench/metrics/<name>.py``. A traced run
+turns the program's own spans on (``repro.serving.tracing``) and its
+readers also see ``chipbench/layers.py``'s reduction. The last line
 of standard output is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (with ``--trace 1`` also ``busy_s``,
 ``window_s`` and a ``breakdown``) and, last, ``checks``: each number
@@ -21,8 +23,8 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -33,7 +35,7 @@ ROOT = Path(__file__).resolve().parents[1]
 #: fixed paths inside the checkout (git-ignored)
 OUT = ROOT / ".chipbench_out"
 
-__all__ = ["RunView", "load_cell", "run_cell", "main"]
+__all__ = ["RunView", "load_cell", "start", "run_cell", "main"]
 
 
 def _paths() -> None:
@@ -43,14 +45,22 @@ def _paths() -> None:
 
 
 class RunView:
-    """What one window measured, as the metric readers see it."""
+    """What one window measured, as the metric readers see it: the
+    window itself (``harness.Window``), the engine's request records, and
+    in a traced run ``trace`` (``trace.reduce_trace``) and ``layers``
+    (``layers.reduce_layers``: per-module, per-scope and per-span
+    times)."""
 
     def __init__(self, window, setup_s: float, peak_flops: float,
-                 trace: Optional[dict]):
+                 trace: Optional[dict], layers: Optional[dict] = None,
+                 records: Optional[dict] = None):
+        self.window = window
         self.window_s = window.t1 - window.t0
         self.setup_s = setup_s
         self.peak_flops = peak_flops
         self.trace = trace
+        self.layers = layers
+        self.records = records or {}
         self.steps = window.steps
         reqs = [r for r in window.reqs.values() if r.submitted is not None
                 and not r.rejected]
@@ -81,14 +91,15 @@ class RunView:
         f = sum(s.flops for s in sel)
         return 100.0 * f / (t * self.peak_flops) if t > 0 and f else None
 
+    def layer(self, name: str) -> Optional[float]:
+        """One of ``layers.reduce_layers``' ``layers`` numbers, or None."""
+        return (self.layers or {}).get("layers", {}).get(name)
+
 
 def _reader(name: str, root: Path = ROOT):
-    path = root / "chipbench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from chipbench.harness import load_module
+    return load_module(root / "chipbench" / "metrics" / f"{name}.py",
+                       f"chipbench_metric_{name.replace('.', '_')}").read
 
 
 def _applies(entry: dict, cell: str) -> bool:
@@ -133,25 +144,69 @@ def _judge(readings: Dict[str, float], limits: dict):
     return bool(ok), checks
 
 
+@contextlib.contextmanager
+def _program_spans():
+    """The program's own step spans on for the block, where it has them."""
+    try:
+        from repro.serving import tracing
+    except ImportError:                      # a program without spans
+        yield
+        return
+    tracing.enable(True)
+    try:
+        yield
+    finally:
+        tracing.enable(False)
+
+
+def start(workload: str, root: Path = ROOT) -> Optional[dict]:
+    """The cell's :func:`load_cell`, with JAX's compile cache on, where
+    the program is there and JAX finds the TPU chips the cell asks for;
+    else None, after a message on standard error."""
+    if not (root / "src" / "repro").is_dir():
+        print(f"chipbench: no program under {root / 'src'}", file=sys.stderr)
+        return None
+    _paths()
+    spec = load_cell(json.loads((root / "BENCHMARK.json").read_text()),
+                     workload, root)
+    from repro.launch.compile_cache import enable_compile_cache
+    cache = enable_compile_cache()
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    devs = jax.devices()
+    chips = spec["cell"]["chips"]
+    if devs[0].platform != "tpu" or len(devs) < chips:
+        print(f"chipbench: {workload} needs {chips} TPU chip(s); JAX "
+              f"found {len(devs)} {devs[0].platform} device(s)",
+              file=sys.stderr)
+        return None
+    print(f"[device] {devs[0].device_kind} x{len(devs)}; compile cache "
+          f"{cache}", file=sys.stderr, flush=True)
+    return spec
+
+
 def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
              t_start: float = T_START, log=print, control: bool = False,
-             engine_hook=None, with_readings: bool = False) -> dict:
+             engine_hook=None, detail: bool = False,
+             step_spans: bool = True) -> dict:
     """Set up, measure one window, check the served tokens; the result.
 
     ``control`` also reads the float8 control on the same sample;
-    ``control`` or ``with_readings`` put every reading of the check under
-    ``"readings"``; ``engine_hook(engine)`` may alter the engine before
-    the window. None is used by a benchmark run."""
+    ``control`` or ``detail`` put every reading of the check under
+    ``"readings"``, and ``detail`` a traced run's whole
+    ``layers.reduce_layers`` under ``"layers"``; ``step_spans`` (traced runs)
+    turns the program's own spans on; ``engine_hook(engine)`` may alter
+    the engine before the window. A benchmark run uses the defaults."""
     import jax
-    from chipbench import check, flops, harness, loadgen
+    from chipbench import check, flops, harness, layers, loadgen
     from chipbench import trace as trace_mod
-    from chipbench.reference import Reference, arch_from_config
 
     config, mix, limits = spec["config"], spec["mix"], spec["limits"]
-    arch = arch_from_config(config["model"])
+    model = harness.model_of(config)
     weight_seed = seed % (1 << 31)
     t_build = time.perf_counter()
-    engine = harness.build_engine(config, arch, weight_seed)
+    engine = harness.build_engine(config, model.arch, weight_seed)
     if engine_hook is not None:
         engine_hook(engine)
     counter = harness.CompileCounter()
@@ -172,14 +227,16 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         jax.profiler.start_trace(str(trace_dir))
     setup_s = time.perf_counter() - t_start
     if trace:
-        with jax.profiler.TraceAnnotation("window"):
-            window = harness.run_window(engine, plan, seconds, arch, spans,
-                                        counter)
+        program_spans = (_program_spans() if step_spans
+                         else contextlib.nullcontext())
+        with program_spans, jax.profiler.TraceAnnotation("window"):
+            window = harness.run_window(engine, plan, seconds, model.cost,
+                                        spans, counter)
         jax.profiler.stop_trace()
     else:
-        window = harness.run_window(engine, plan, seconds, arch, spans,
+        window = harness.run_window(engine, plan, seconds, model.cost, spans,
                                     counter)
-    stats = engine.stats
+    stats, records = engine.stats, engine.records
     log(f"[window] {window.t1 - window.t0:.3f}s: {len(window.steps)} steps "
         f"({sum(s.kind == 'chunk' for s in window.steps)} chunks), "
         f"migrations so far {stats.migrations}; compiles in the window "
@@ -188,15 +245,18 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     peak_bytes = int(mem.get("peak_bytes_in_use", 0))
     del engine, stats
     gc.collect()
-    reduced = None
+    reduced = by_layer = None
     if trace:
         path = trace_mod.find_trace(trace_dir)
         t0 = time.perf_counter()
         reduced = trace_mod.reduce_trace(path) if path else None
+        t1 = time.perf_counter()
+        by_layer = layers.reduce_layers(path) if path else None
         log(f"[trace] {path} ({path.stat().st_size if path else 0} B) "
-            f"reduced in {time.perf_counter() - t0:.1f}s")
+            f"reduced in {t1 - t0:.1f}s, by layer in "
+            f"{time.perf_counter() - t1:.1f}s")
         shutil.rmtree(trace_dir, ignore_errors=True)
-    run = RunView(window, setup_s, peak, reduced)
+    run = RunView(window, setup_s, peak, reduced, by_layer, records)
     entries = spec["per_layer"] if trace else spec["end_to_end"]
     metrics = {}
     for m in entries:
@@ -207,8 +267,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     t0 = time.perf_counter()
     picked = check.sample(window.reqs.values(), seed,
                           limits["sample_tokens"], limits["sample_requests"])
-    readings = check.readings(Reference(arch, weight_seed), picked,
-                              ("f32", "fp8") if control else ("f32",))
+    readings = check.readings(model.reference(model.arch, weight_seed),
+                              picked, ("f32", "fp8") if control else ("f32",))
     ok, checks = _judge(readings, limits)
     log(f"[check] {len(picked)} requests, reference "
         f"{time.perf_counter() - t0:.1f}s; widest gap "
@@ -222,10 +282,14 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     if trace and reduced:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
+        # idle time by the innermost span open, program spans included
+        gaps = by_layer["idle_gaps"] if by_layer else reduced["idle_gaps"]
         result["breakdown"] = {"device_ops": reduced["device_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
-    if control or with_readings:
+                               "idle_gaps": gaps[:10]}
+    if control or detail:
         result["readings"] = readings
+    if detail and by_layer:
+        result["layers"] = by_layer
     result["checks"] = checks
     return result
 
@@ -237,26 +301,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    if not (ROOT / "src" / "repro").is_dir():
-        print(f"chipbench: no program under {ROOT / 'src'}", file=sys.stderr)
-        return 2
-    _paths()
-    spec = load_cell(json.loads((ROOT / "BENCHMARK.json").read_text()),
-                     args.workload)
-    from repro.launch.compile_cache import enable_compile_cache
-    cache = enable_compile_cache()
-    import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    devs = jax.devices()
-    chips = spec["cell"]["chips"]
-    if devs[0].platform != "tpu" or len(devs) < chips:
-        print(f"chipbench: {args.workload} needs {chips} TPU chip(s); JAX "
-              f"found {len(devs)} {devs[0].platform} device(s)",
-              file=sys.stderr)
+    spec = start(args.workload)
+    if spec is None:
         return 1
-    print(f"[device] {devs[0].device_kind} x{len(devs)}; compile cache "
-          f"{cache}", file=sys.stderr, flush=True)
     result = run_cell(spec, args.seed, args.seconds, bool(args.trace),
                       log=lambda s: print(s, file=sys.stderr, flush=True))
     for name, c in result["checks"].items():

@@ -33,20 +33,14 @@ def main(argv=None) -> int:
     ap.add_argument("--rates", required=True)
     args = ap.parse_args(argv)
     from chipbench import harness, loadgen, run
-    spec = run.load_cell(json.loads((ROOT / "BENCHMARK.json").read_text()),
-                         args.workload)
-    from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    import jax
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    if jax.devices()[0].platform != "tpu":
-        print("sweep: needs a TPU", file=sys.stderr)
+    spec = run.start(args.workload)
+    if spec is None:
         return 1
+    import jax
     from chipbench.flops import peak_flops
-    from chipbench.reference import arch_from_config
-    arch = arch_from_config(spec["config"]["model"])
-    engine = harness.build_engine(spec["config"], arch, args.seed % (1 << 31))
+    model = harness.model_of(spec["config"])
+    engine = harness.build_engine(spec["config"], model.arch,
+                                  args.seed % (1 << 31))
     counter = harness.CompileCounter()
     harness.warm_up(engine)
     peak = peak_flops(jax.devices()[0].device_kind)
@@ -55,7 +49,7 @@ def main(argv=None) -> int:
                                              rate=rate))
         plan = loadgen.plan_requests(mix, args.seed + i, args.seconds,
                                      id_base=(i + 1) * (1 << 30))
-        window = harness.run_window(engine, plan, args.seconds, arch,
+        window = harness.run_window(engine, plan, args.seconds, model.cost,
                                     harness.Spans(False), counter)
         view = run.RunView(window, 0.0, peak, None)
         waiting = sum(1 for r in window.reqs.values() if not r.token_times)

@@ -6,6 +6,7 @@ cells'), with the cells' sample (up to 32 requests): on seeds 1-8 the
 bfloat16 program read a mean gap of at most 0.0214 and the float8
 control at least 0.142; the three faults read 0.26 or more on seeds 3-5."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -37,7 +38,8 @@ def _patch_decode(engine, after):
     orig = engine._exec_decode
 
     def wrapped():
-        before = (engine.tokens, engine.cache)
+        # the step donates the cache it is given: hold a copy of it
+        before = (engine.tokens, jax.tree.map(jnp.copy, engine.cache))
         orig()
         after(engine, *before)
     engine._exec_decode = wrapped

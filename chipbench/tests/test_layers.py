@@ -154,3 +154,35 @@ def test_queue_wait_from_admission_steps():
     # a program that keeps no admission step reads nothing
     bare = {i: types.SimpleNamespace() for i in range(10)}
     assert queue_wait_ms(window, bare, starts) is None
+
+
+@pytest.mark.parametrize("name", ["decode_device_ms", "decode_moe_ms",
+                                  "decode_attn_ms", "control_ms",
+                                  "migrate_pct"])
+def test_traced_run_view_reader_sees_the_reduction(name):
+    """A metric reader reads a traced run's per-scope and per-span times:
+    the numbers of ``reduce_layers`` on the recorded trace reach it
+    through ``RunView``, and a run without them reads nothing."""
+    from chipbench import run
+    from chipbench.layers import reduce_layers
+    window = types.SimpleNamespace(t0=0.0, t1=1.0, reqs={}, steps=[],
+                                   step_start={})
+    lay = reduce_layers(_data(SCOPED))
+    view = run.RunView(window, 1.0, 197e12, None, lay)
+    got = run._reader(name)(view)
+    assert got == lay["layers"][name] and got > 0
+    assert run._reader(name)(run.RunView(window, 1.0, 197e12, None)) is None
+
+
+def test_queue_wait_reader_reads_the_window_step_starts():
+    from chipbench import run
+    reqs = {i: types.SimpleNamespace(req_id=i, submitted=float(i), due=0.0,
+                                     rejected=False, token_times=[])
+            for i in range(4)}
+    window = types.SimpleNamespace(t0=0.0, t1=10.0, reqs=reqs, steps=[],
+                                   step_start={i: i + 0.25 for i in range(4)})
+    records = {i: types.SimpleNamespace(admitted_step=i) for i in range(4)}
+    read = run._reader("queue_wait_ms")
+    assert read(run.RunView(window, 1.0, 197e12, None, None, records)) \
+        == pytest.approx(250.0)
+    assert read(run.RunView(window, 1.0, 197e12, None)) is None
